@@ -3,7 +3,8 @@
 Importing this module applies the process platform config (see
 ``repro.utils.platform``): ``REPRO_EMULATED_DEVICES=8`` runs the same
 benches on an emulated 8-device CPU mesh that a real accelerator job runs
-on hardware — no per-job ``XLA_FLAGS`` surgery.
+on hardware — no per-job ``XLA_FLAGS`` surgery — and turns on the
+persistent compile cache (``rplat.enable_compile_cache``).
 
 Timing runs through ``repro.telemetry.trace``: ``time_call`` returns a
 :class:`~repro.telemetry.trace.Timing` (a float carrying ``compile_us`` /
@@ -19,6 +20,7 @@ from typing import Any, Callable, Dict, List, Optional
 from repro.utils import platform as rplat  # pre-jax: may set device flags
 
 rplat.apply_emulated_devices()
+rplat.enable_compile_cache()
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402

@@ -196,6 +196,7 @@ def _check_one_partition(report: Report, scens, label: str) -> None:
     """The structural lane-contract assertions for one scenario list that
     must form a single partition."""
     import jax
+    from jax.extend import core as jex_core
     import numpy as np
 
     from repro.core.sweep import lane_program, partition_scenarios
@@ -228,10 +229,10 @@ def _check_one_partition(report: Report, scens, label: str) -> None:
     used = set()
     for eqn in jaxpr.eqns:
         for v in eqn.invars:
-            if not isinstance(v, jax.core.Literal):
+            if not isinstance(v, jex_core.Literal):
                 used.add(v)
     for v in jaxpr.outvars:
-        if not isinstance(v, jax.core.Literal):
+        if not isinstance(v, jex_core.Literal):
             used.add(v)
 
     # Channel / power-control objects pack WHOLESALE by design: all fields
@@ -344,16 +345,16 @@ def check_lane_contract(report: Report,
 def _iter_jaxprs(jaxpr):
     """Yield ``jaxpr`` and every sub-jaxpr reachable through eqn params
     (pjit, scan, cond, custom_jvp, ...)."""
-    import jax
+    from jax.extend import core as jex_core
 
     yield jaxpr
     for eqn in jaxpr.eqns:
         for val in eqn.params.values():
             vals = val if isinstance(val, (tuple, list)) else (val,)
             for v in vals:
-                if isinstance(v, jax.core.ClosedJaxpr):
+                if isinstance(v, jex_core.ClosedJaxpr):
                     yield from _iter_jaxprs(v.jaxpr)
-                elif isinstance(v, jax.core.Jaxpr):
+                elif isinstance(v, jex_core.Jaxpr):
                     yield from _iter_jaxprs(v)
 
 
@@ -657,16 +658,16 @@ _PARTICIPATION_PATH = "src/repro/service/participation.py"
 def _key_invar_live(closed_jaxpr) -> bool:
     """Whether any top-level input variable of the jaxpr is consumed by an
     equation (or returned)."""
-    import jax
+    from jax.extend import core as jex_core
 
     jaxpr = closed_jaxpr.jaxpr
     used = set()
     for eqn in jaxpr.eqns:
         for v in eqn.invars:
-            if not isinstance(v, jax.core.Literal):
+            if not isinstance(v, jex_core.Literal):
                 used.add(v)
     for v in jaxpr.outvars:
-        if not isinstance(v, jax.core.Literal):
+        if not isinstance(v, jex_core.Literal):
             used.add(v)
     return any(v in used for v in jaxpr.invars)
 

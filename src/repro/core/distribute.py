@@ -22,14 +22,18 @@ path):
   accelerator meshes; the CPU backend cannot reuse donated buffers, so
   donation is skipped there rather than tripping jax's warning.
 
-Exactness contract: sharding only changes data *placement* — a sharded
-partition runs the identical ``vmap`` jaxpr that ``mode="vmap"`` jits, so
-lanes are bit-identical to the default mode (and hence to per-scenario
-``fedpg.monte_carlo``) wherever that mode is bitwise; the padded lanes
-recompute the last real lane and never reach the result.
-``tests/test_distribute.py`` plus the golden-trace suite enforce this on an
-8-device emulated CPU mesh (``REPRO_EMULATED_DEVICES=8``, applied by
-``repro.utils.platform`` before JAX initialises).
+Exactness: sharding only changes data *placement* — every device runs
+the one-device program that ``mode="vmap"`` compiles, on its own block of
+lanes and MC keys (:class:`PartitionProgram`), so each lane is
+bit-identical to ``mode="vmap"`` run on that block; the padded lanes
+recompute the last real lane and never reach the result.  On one device
+the block is the whole partition, so lanes are bit-identical to the
+default mode (and hence to per-scenario ``fedpg.monte_carlo``).  Across
+devices the block is smaller, and XLA compiles the ``vmap`` program for
+each batch size: a block can differ from the whole batch in the last
+bits.  ``tests/test_distribute.py`` plus the golden-trace suite enforce
+this (``REPRO_EMULATED_DEVICES=8`` runs them across emulated devices,
+applied by ``repro.utils.platform`` before JAX initialises).
 
 The *agent* axis inside a round is the other shardable dimension: build a
 mesh with :func:`agent_mesh_for` and pass it to
@@ -39,6 +43,7 @@ local_stack=True)``.
 """
 from __future__ import annotations
 
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Any, Dict, Optional, Tuple
 
@@ -54,7 +59,7 @@ MC_AXIS = "mc"
 __all__ = [
     "LANE_AXIS", "MC_AXIS", "Placement", "agent_mesh_for",
     "default_sweep_mesh", "dispatch_partition", "pad_lanes",
-    "place_partition", "plan_placement",
+    "PartitionProgram", "place_partition", "plan_placement",
 ]
 
 
@@ -158,22 +163,79 @@ def place_partition(
     # them) — donation only pays on accelerator meshes
     donate = donate and mesh.devices.flat[0].platform != "cpu"
     placement = plan_placement(mesh, n_lanes, keys.shape[0])
-    key_sh = NamedSharding(mesh, placement.key_spec)
-    out_sh = NamedSharding(mesh, placement.out_spec)
-    keys_placed = jax.device_put(keys, key_sh)
-    if placement.n_lanes == 0:
-        jitted = jax.jit(lane_fn, in_shardings=(key_sh, key_sh),
-                         out_shardings=out_sh)
-        return jitted, packed, keys_placed, placement
-    lane_sh = NamedSharding(mesh, placement.lane_spec)
-    placed = jax.device_put(pad_lanes(packed, placement.n_pad), lane_sh)
-    jitted = jax.jit(
-        jax.vmap(lane_fn, in_axes=(0, None)),
-        in_shardings=(lane_sh, key_sh),
-        out_shardings=out_sh,
-        donate_argnums=(0,) if donate else (),
-    )
-    return jitted, placed, keys_placed, placement
+    fn = jax.vmap(lane_fn, in_axes=(0, None)) if placement.n_lanes \
+        else lane_fn
+    jitted = PartitionProgram(fn, placement, donate=donate)
+    keys_placed = jax.device_put(keys, NamedSharding(mesh, placement.key_spec))
+    if placement.n_lanes:
+        packed = jax.device_put(pad_lanes(packed, placement.n_pad),
+                                NamedSharding(mesh, placement.lane_spec))
+    return jitted, packed, keys_placed, placement
+
+
+class PartitionProgram:
+    """One partition program laid across a mesh, one executable per device.
+
+    Every device runs ``jax.jit(fn)`` -- the one-device program that
+    ``mode="vmap"`` compiles for a partition of that many lanes -- on its
+    own block of lanes and MC keys; lanes and MC runs are independent, so
+    no device needs another's data.  The blocks are assembled into arrays
+    sharded as ``placement.out_spec``.
+
+    Why not one program over the mesh: XLA's partitioner cannot split the
+    fused OTA uplink kernel (a Mosaic custom call) that lanes run on a TPU,
+    and a ``shard_map`` module, though it runs the same jaxpr, may be
+    compiled differently from a one-device module (on the CPU a reduction
+    in the rollout returns changed layout, and rewards their last bits).
+    The price is one compile per device.  ``donate`` donates the packed lane
+    blocks.  Call with ``packed`` and ``keys`` placed on the mesh as
+    ``placement`` says (see :func:`place_partition`).
+    """
+
+    def __init__(self, fn, placement: Placement, *, donate: bool = False):
+        self.placement = placement
+        self._jitted = jax.jit(
+            fn, donate_argnums=(0,) if donate and placement.n_lanes else ())
+        self._compiled: Dict[Any, Any] = {}
+
+    def _blocks(self, packed, keys):
+        """(device, packed block, key block) for every device of the mesh."""
+        def block(x, device):
+            (data,) = [s.data for s in x.addressable_shards
+                       if s.device == device]
+            return data
+        return [(d, jax.tree.map(lambda x: block(x, d), packed),
+                 block(keys, d)) for d in self.placement.mesh.devices.flat]
+
+    def compile(self, packed, keys) -> "PartitionProgram":
+        """Compile every device's executable ahead of the call; the
+        compiles are independent and run in parallel."""
+        todo = {d: self._jitted.lower(pk, ks)
+                for d, pk, ks in self._blocks(packed, keys)
+                if d not in self._compiled}
+        with ThreadPoolExecutor(max(len(todo), 1)) as pool:
+            self._compiled.update(zip(
+                todo, pool.map(lambda lowered: lowered.compile(),
+                               todo.values())))
+        return self
+
+    def __call__(self, packed, keys):
+        self.compile(packed, keys)
+        outs = [self._compiled[d](pk, ks)
+                for d, pk, ks in self._blocks(packed, keys)]
+        mesh, spec = self.placement.mesh, self.placement.out_spec
+        sharding = NamedSharding(mesh, spec)
+
+        def assemble(*blocks):
+            shape = list(blocks[0].shape)
+            for dim, axes in enumerate(spec):
+                for axis in (axes if isinstance(axes, tuple) else (axes,)):
+                    if axis is not None:
+                        shape[dim] *= mesh.shape[axis]
+            return jax.make_array_from_single_device_arrays(
+                tuple(shape), sharding, list(blocks))
+
+        return jax.tree.map(assemble, *outs)
 
 
 def dispatch_partition(
@@ -191,9 +253,9 @@ def dispatch_partition(
     ``(mc_runs, ...)`` leaves.  Callers slice real lanes / replicate and
     defer ``block_until_ready`` until they materialise results.
 
-    Compilation is split out ahead-of-time (``lower().compile()`` — still
-    exactly one XLA compile per partition, the compile-budget contract's
-    invariant) so the ``compile`` and ``dispatch`` phases land as separate
+    Compilation is split out ahead-of-time (one XLA compile per partition
+    and device: on one device the compile-budget contract's one per
+    partition) so the ``compile`` and ``dispatch`` phases land as separate
     ``repro.telemetry.trace`` spans in sweep trace exports.
     """
     from repro.telemetry import trace as rtrace
@@ -202,8 +264,8 @@ def dispatch_partition(
         lane_fn, packed, keys, mesh, donate=donate)
     with rtrace.span("compile", lanes=placement.n_lanes,
                      pad=placement.n_pad, devices=mesh.size):
-        compiled = jitted.lower(placed, keys_placed).compile()
+        jitted.compile(placed, keys_placed)
     with rtrace.span("dispatch", lanes=placement.n_lanes,
                      devices=mesh.size):
-        out = compiled(placed, keys_placed)
+        out = jitted(placed, keys_placed)
     return out, placement

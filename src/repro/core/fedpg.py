@@ -622,7 +622,7 @@ def _make_agent_sharded_round_fn(
     gain mean, debias) uses the true agent count.  Without ``agent_blocks``
     a non-dividing fleet still raises.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.rl.sampler import discounted_return
@@ -909,7 +909,7 @@ def _make_agent_sharded_round_fn(
             body, mesh=mesh,
             in_specs=(P(), P(axis_name), stack_specs, P()),
             out_specs=(P(), metric_specs),
-            check_rep=False,
+            check_vma=False,
         )(theta, agent_keys, lane_stacks, key_chan)
 
     if part is None:
@@ -932,7 +932,7 @@ def _make_agent_sharded_round_fn(
             local_round_streamed_svc, mesh=mesh,
             in_specs=(P(), P(axis_name), stack_specs, P(), P(), P(), P()),
             out_specs=(P(), metric_specs),
-            check_rep=False,
+            check_vma=False,
         )(theta, agent_keys, lane_stacks, key_chan, state.round_idx,
           state.part_key, state.sched_key)
         state_next = state._replace(theta=theta_next,

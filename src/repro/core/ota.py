@@ -75,17 +75,6 @@ def _noise_enabled(sigma: Scalar) -> bool:
     return True
 
 
-def _axis_size(name: str) -> Scalar:
-    """Mesh-axis size inside shard_map.  ``jax.lax.axis_size`` only exists on
-    newer jax; the pinned 0.4.x falls back to a psum of ones — a *traced*
-    count, so callers that need a static agent count (per-agent power-control
-    moments, float64-folded scales) must pass one explicitly (see the
-    ``n_agents`` kwarg on :func:`aggregate`)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(jnp.ones((), jnp.int32), name)
-
-
 @dataclass(frozen=True)
 class OTAConfig:
     """Static configuration of the over-the-air uplink.
@@ -239,9 +228,9 @@ def aggregate(
     an axis-name tuple selects the shard_map/psum forms, ``local_stack=True``
     when each shard carries a stack of agents.  ``key`` is required for
     noisy forms; ``n_agents`` is the static global agent count when the
-    caller knows it (needed by per-agent power policies and traced-count
-    jax versions).  ``backend``/``spec`` pick the implementation —
-    see :class:`AggregateSpec`.  ``gains`` overrides the channel draw
+    caller knows it (needed by per-agent power policies).
+    ``backend``/``spec`` pick the implementation — see
+    :class:`AggregateSpec`.  ``gains`` overrides the channel draw
     (stacked form only, for equivalence tests).
 
     ``agent_blocks`` selects the *streaming* blocked-scan evaluation of the
@@ -523,7 +512,7 @@ def _exact_mean_axis_stacked(
     if n_agents is None:
         idx_stride = 1
         for name in axis_names:
-            idx_stride = idx_stride * _axis_size(name)
+            idx_stride = idx_stride * jax.lax.axis_size(name)
         n_total: Scalar = idx_stride * n_local
     else:
         n_total = n_agents
@@ -1008,13 +997,12 @@ def _psum_axis_stacked_streamed(
 
 def _flat_axis_index(axis_names: Sequence[str]) -> Tuple[jax.Array, Scalar]:
     """(flattened shard index, total shard count) over the given mesh axes
-    (row-major, matching the historical ``local_gain`` indexing).  The count
-    is traced on jax versions without ``lax.axis_size``."""
+    (row-major, matching the historical ``local_gain`` indexing)."""
     idx = jnp.zeros((), jnp.int32)
     stride: Scalar = 1
     for name in reversed(tuple(axis_names)):
         idx = idx + jax.lax.axis_index(name) * stride
-        stride = stride * _axis_size(name)
+        stride = stride * jax.lax.axis_size(name)
     return idx, stride
 
 
@@ -1053,11 +1041,10 @@ def _psum_axis(
     The per-agent gain scaling happens *before* the psum, so OTA adds zero
     communication volume over exact data-parallel aggregation — which is the
     paper's efficiency claim transplanted to the interconnect.  ``n_agents``
-    is the static total agent count when known; without it the count is a
-    traced psum of ones (old jax has no ``lax.axis_size``), which keeps the
-    maths right but means debiased per-agent-policy configs must carry an
-    explicit ``update_scale`` (a traced count cannot key the closed-form
-    effective moments).
+    is the static total agent count when known; without it the count is the
+    mesh size, which keeps the maths right, but debiased per-agent-policy
+    configs must then carry an explicit ``update_scale`` (their closed-form
+    effective moments are keyed on ``n_agents``).
     """
     key_h, key_n = jax.random.split(key)
     h = local_gain(cfg, key_h, axis_names, n_agents)

@@ -233,14 +233,9 @@ class HeterogeneousBudget(PowerPolicy):
         return jnp.broadcast_to(self._budgets(c.shape[-1], c.dtype), c.shape)
 
     def apply_indexed(self, c, idx, n_agents):
-        if isinstance(n_agents, (int, np.integer)):
-            # static count: fold the step in as a Python literal (matches
-            # what the stacked form's linspace would produce)
-            step = (self.p_max - self.p_min) / max(int(n_agents) - 1, 1)
-        else:
-            # traced count (old jax has no lax.axis_size): compute at runtime
-            step = (self.p_max - self.p_min) / jnp.maximum(
-                n_agents - 1, 1).astype(c.dtype)
+        # the agent count is static (mesh axis sizes are Python ints): fold
+        # the step in as a literal, matching the stacked form's linspace
+        step = (self.p_max - self.p_min) / max(int(n_agents) - 1, 1)
         return (self.p_min + idx.astype(c.dtype) * step) * jnp.ones_like(c)
 
     def closed_form_moments(self, base, n_agents=None):

@@ -94,8 +94,9 @@ from repro.telemetry.probes import RoundTelemetry, TelemetryConfig
 # reassociate.  ``sharded`` is the vmap program with its lane/MC axes laid
 # across a device mesh (``repro.core.distribute``): partitions dispatch
 # asynchronously, uneven lane counts pad with masked replicate-lanes, and
-# results stay bit-identical to ``vmap`` — sharding only moves data
-# placement, never the per-lane jaxpr.
+# every device runs the ``vmap`` program on its block of lanes (results
+# are bit-identical to ``vmap`` on that block, and so on one device to
+# ``vmap``; see ``repro.core.distribute``).
 MODES = ("map", "vmap", "sharded")
 
 
@@ -732,8 +733,10 @@ def sweep(
     ``mode="sharded"`` lays each partition's (lanes x mc_runs) batch across
     a device mesh (``mesh=`` from ``launch.mesh.make_sweep_mesh``, default
     all devices on the lane axis), dispatches partitions asynchronously and
-    defers ``block_until_ready`` to result materialisation; lanes stay
-    bit-identical to ``mode="vmap"`` (see ``repro.core.distribute``).
+    defers ``block_until_ready`` to result materialisation; each device
+    runs the ``mode="vmap"`` program on its block of lanes, bit-identical
+    to ``mode="vmap"`` on that block (on one device, the whole partition;
+    see ``repro.core.distribute``).
 
     ``telemetry`` (a :class:`repro.telemetry.TelemetryConfig` with active
     probes) fills ``SweepResult.history.telemetry`` with ``(S, mc, K)``

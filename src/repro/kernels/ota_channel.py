@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import CompilerParams as _CompilerParams
 
 LANES = 128
 
@@ -50,9 +49,12 @@ def _kernel(v_ref, o_ref, *, sigma: float, scale: float, seed: int,
         base = _mix(counter, jnp.uint32(seed) * jnp.uint32(0x9E3779B9))
         u1 = _mix(base, jnp.uint32(0xA511E9B3))
         u2 = _mix(base, jnp.uint32(0x63D83595))
-        # uniform in (0, 1]: (bits >> 8) * 2^-24, offset by 2^-25 to avoid 0
-        f1 = (u1 >> 8).astype(jnp.float32) * (1.0 / (1 << 24)) + (1.0 / (1 << 25))
-        f2 = (u2 >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
+        # uniform in (0, 1]: (bits >> 8) * 2^-24, offset by 2^-25 to avoid 0;
+        # the 24-bit values go through int32 (Mosaic has no uint32 -> f32)
+        b1 = (u1 >> 8).astype(jnp.int32).astype(jnp.float32)
+        b2 = (u2 >> 8).astype(jnp.int32).astype(jnp.float32)
+        f1 = b1 * (1.0 / (1 << 24)) + (1.0 / (1 << 25))
+        f2 = b2 * (1.0 / (1 << 24))
         # Box-Muller
         r = jnp.sqrt(-2.0 * jnp.log(f1))
         n = r * jnp.cos(2.0 * jnp.pi * f2)
@@ -95,7 +97,7 @@ def ota_channel_apply(
         in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, LANES), v.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,

@@ -44,7 +44,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import CompilerParams as _CompilerParams
 from repro.utils.tree import ceil_div, next_pow2
 
 LANES = 128
@@ -66,6 +65,13 @@ def _mix(x: jax.Array, salt: jax.Array) -> jax.Array:
     return x ^ (x >> 16)
 
 
+def _u24_to_f32(bits: jax.Array) -> jax.Array:
+    """The top 24 bits of ``bits`` as exact float32 integers.  Mosaic has no
+    uint32 -> float32 cast; the shifted value is below 2^24, so the detour
+    through int32 is lossless and gives the same floats in interpret mode."""
+    return (bits >> 8).astype(jnp.int32).astype(jnp.float32)
+
+
 def _counter_noise(seed: jax.Array, start: jax.Array, shape) -> jax.Array:
     """Standard-normal noise for ``shape`` elements at absolute flat offset
     ``start``: threefry-free counter PRNG -> Box-Muller, bitwise identical
@@ -76,8 +82,8 @@ def _counter_noise(seed: jax.Array, start: jax.Array, shape) -> jax.Array:
     u1 = _mix(base, jnp.uint32(0xA511E9B3))
     u2 = _mix(base, jnp.uint32(0x63D83595))
     # uniform in (0, 1]: (bits >> 8) * 2^-24, offset by 2^-25 to avoid 0
-    f1 = (u1 >> 8).astype(jnp.float32) * (1.0 / (1 << 24)) + (1.0 / (1 << 25))
-    f2 = (u2 >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
+    f1 = _u24_to_f32(u1) * (1.0 / (1 << 24)) + (1.0 / (1 << 25))
+    f2 = _u24_to_f32(u2) * (1.0 / (1 << 24))
     r = jnp.sqrt(-2.0 * jnp.log(f1))
     return r * jnp.cos(2.0 * jnp.pi * f2)
 
@@ -96,6 +102,7 @@ def _fused_kernel(consts_ref, seed_ref, h_ref, g_ref, *state_refs,
     g = g_ref[...]                                         # (A, per_block)
     v = jax.lax.dot_general(
         h, g.astype(jnp.float32), (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,               # f32 on the MXU
         preferred_element_type=jnp.float32,
     )                                                      # (1, per_block)
     if with_noise:
@@ -199,7 +206,7 @@ def _call(consts, seed, gains, grads, states, *, mode: str, with_noise: bool,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(consts, seed, gains.reshape(1, n_agents), grads, *states)
     return out if isinstance(out, (tuple, list)) else (out,)

@@ -98,7 +98,8 @@ def ota_fused_ref(
     """
     v = jax.lax.dot_general(
         gains.astype(jnp.float32).reshape(1, -1), grads.astype(jnp.float32),
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     ).reshape(-1)
     if noise is not None:
         v = v + jnp.asarray(sigma, jnp.float32) * noise.astype(jnp.float32)

@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import CompilerParams as _CompilerParams
 
 
 def _kernel(dax_ref, da_ref, b_ref, c_ref, y_ref, state_scr, *, chunk: int):
@@ -125,7 +124,7 @@ def ssd_scan(
         out_specs=pl.BlockSpec((None, chunk, p), x_map),
         out_shape=jax.ShapeDtypeStruct((b * h, s, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -199,7 +199,7 @@ def make_psum_train_step(model: Model, tcfg: TrainConfig, mesh, data_axes=("data
     (pure DP); used for equivalence tests and the paper-faithful RL-scale
     runs, not for the tensor-parallel production meshes."""
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     opt = make_optimizer(tcfg)
     ota_cfg = tcfg.ota_config()
@@ -226,7 +226,7 @@ def make_psum_train_step(model: Model, tcfg: TrainConfig, mesh, data_axes=("data
             mesh=mesh,
             in_specs=(rep, bspec, rep),
             out_specs=(bspec, rep),
-            check_rep=False,
+            check_vma=False,
         )
         losses, grads = sm(state.params, batch, key)
         grads, gnorm = clip_by_global_norm(grads, tcfg.clip_norm)

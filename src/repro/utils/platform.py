@@ -15,6 +15,7 @@ Typical uses::
 
     rplat.enable_x64()                  # float64 for reference numerics
     rplat.set_platform("cpu")           # force CPU even on an accelerator
+    rplat.enable_compile_cache()        # persistent compile cache (see below)
 
 CI and test runs opt into device emulation with the ``REPRO_EMULATED_DEVICES``
 environment variable (see :func:`emulated_device_count` /
@@ -24,6 +25,7 @@ loads, replacing per-job ``XLA_FLAGS`` string surgery.
 from __future__ import annotations
 
 import os
+from pathlib import Path
 from typing import Optional
 
 _DEVCOUNT_FLAG = "--xla_force_host_platform_device_count"
@@ -94,6 +96,33 @@ def enable_x64(use_x64: bool = True) -> None:
     import jax
 
     jax.config.update("jax_enable_x64", use_x64)
+
+
+# JAX reads this variable itself; when it is set, it names the cache.
+COMPILE_CACHE_VAR = "JAX_COMPILATION_CACHE_DIR"
+
+# The source checkout holding this module (src/repro/utils/platform.py).
+_CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    With ``JAX_COMPILATION_CACHE_DIR`` set the cache stays where it says
+    and no directory is set here.  Otherwise the cache is the fixed
+    ``.jax_cache`` of this source checkout: it never depends on a
+    temporary name, a pid or the time.  Every program is cached however
+    fast it compiled.  Call before the first compile: JAX decides once per
+    process whether the cache is used.
+    """
+    import jax
+
+    path = os.environ.get(COMPILE_CACHE_VAR, "")
+    if not path:
+        path = str(_CHECKOUT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
 
 
 def describe() -> dict:

@@ -21,6 +21,11 @@ rplat.apply_emulated_devices()
 import jax  # noqa: E402
 import pytest  # noqa: E402
 
+# Tests never read or write the persistent compile cache, even where a
+# benchmark module they import turns it on: a cache hit skips the backend
+# compile that the compile-count tests count.
+jax.config.update("jax_enable_compilation_cache", False)
+
 
 def pytest_addoption(parser):
     parser.addoption(

@@ -170,7 +170,7 @@ def test_add_awgn_backend_equivalence_noiseless(key):
 def test_dispatcher_axis_forms_match_legacy(key):
     """Axis and axis-stacked forms through the dispatcher == the legacy
     psum entry points, bitwise (same ops inside shard_map)."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n = jax.local_device_count()
@@ -193,9 +193,9 @@ def test_dispatcher_axis_forms_match_legacy(key):
     specs = ({k: P("data") for k in g},)
     out_specs = {k: P() for k in g}
     a = shard_map(new, mesh=mesh, in_specs=specs, out_specs=out_specs,
-                  check_rep=False)(g)
+                  check_vma=False)(g)
     b = shard_map(old, mesh=mesh, in_specs=specs, out_specs=out_specs,
-                  check_rep=False)(g)
+                  check_vma=False)(g)
     for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
@@ -210,9 +210,9 @@ def test_dispatcher_axis_forms_match_legacy(key):
     in_sp = ({k: P("data") for k in g},)
     out_sp = ({k: P() for k in g}, P("data"))
     a2 = shard_map(new_stacked, mesh=mesh, in_specs=in_sp, out_specs=out_sp,
-                   check_rep=False)(g)
+                   check_vma=False)(g)
     b2 = shard_map(old_stacked, mesh=mesh, in_specs=in_sp, out_specs=out_sp,
-                   check_rep=False)(g)
+                   check_vma=False)(g)
     for x, y in zip(jax.tree.leaves(a2), jax.tree.leaves(b2)):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 

@@ -1,10 +1,11 @@
 """Sharded sweep execution over a device mesh.
 
 The contract under test: ``sweep(..., mode="sharded")`` is **bit-identical**
-to ``mode="vmap"`` — sharding moves data placement, never the per-lane
-jaxpr — for every golden scenario (LQR at its documented rtol), with uneven
-lane counts padded by masked replicate-lanes and partitions dispatched
-asynchronously.  Plus the agent-axis hook: ``fedpg.run(..., agent_mesh=...)``
+to ``mode="vmap"`` run on each device's block of lanes and MC keys — every
+device runs the one-device program that mode compiles — for every golden
+scenario (LQR at its documented rtol), with uneven lane counts padded by
+masked replicate-lanes and partitions dispatched asynchronously.  On one
+device the block is the whole batch.  Plus the agent-axis hook: ``fedpg.run(..., agent_mesh=...)``
 runs each round's fleet in the production shard_map/psum form.
 
 Everything here passes on a single device (degenerate 1-device mesh); CI
@@ -15,6 +16,8 @@ additionally runs this file under an emulated 8-device mesh:
 
 which is also the recommended way to develop against it locally.
 """
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -30,7 +33,9 @@ from repro.core.ota import (
     OTAConfig, aggregate_stacked, psum_aggregate, psum_aggregate_stacked,
 )
 from repro.core.power_control import HeterogeneousBudget
-from repro.core.sweep import Scenario, grid, sweep
+from repro.core.sweep import (
+    Scenario, grid, lane_program, partition_scenarios, sweep,
+)
 from repro.launch.mesh import make_agent_mesh, make_sweep_mesh
 from repro.rl.env import LandmarkNav
 from repro.rl.policy import MLPPolicy
@@ -49,6 +54,34 @@ def _hist_equal(a, b) -> bool:
     return all(
         np.array_equal(np.asarray(x), np.asarray(y)) for x, y in zip(a, b)
     )
+
+
+def _vmap_per_block(env, pol, scens, key, mc, mesh):
+    """The sharded contract's reference: the partition's ``mode="vmap"``
+    program run on one device over each device's block of lanes and MC
+    keys, stacked back as ``(lanes, mc, K)`` histories (``(mc, K)`` on the
+    replicate path).  XLA compiles the program for each batch size, so
+    blocks of another size may differ from the whole batch in the last
+    bit; on one device the block is the whole batch."""
+    (part,) = partition_scenarios(scens)
+    packed, fn, _ = lane_program(env, pol, part, mc_runs=mc)
+    keys = jax.random.split(key, mc)
+    n = len(scens) if packed else 0
+    placement = plan_placement(mesh, n, mc)
+    packed = pad_lanes(packed, placement.n_pad)
+    key_axes = jax.tree.leaves(tuple(placement.key_spec))
+    key_blk = mc // math.prod(mesh.shape[a] for a in key_axes)
+    lane_blk = (n + placement.n_pad) // mesh.shape["lane"] if n else 0
+    run = jax.jit(fn)
+    rows = []
+    for i in range(0, max(n + placement.n_pad, 1), max(lane_blk, 1)):
+        blocks = [run(jax.tree.map(lambda x: x[i:i + lane_blk], packed),
+                      keys[j:j + key_blk]) for j in range(0, mc, key_blk)]
+        rows.append(jax.tree.map(
+            lambda *b: np.concatenate(b, axis=1 if n else 0), *blocks))
+    if not n:
+        return rows[0]
+    return jax.tree.map(lambda *r: np.concatenate(r, axis=0), *rows)
 
 
 # ---------------------------------------------------------------------------
@@ -114,7 +147,7 @@ def test_pad_lanes_replicates_last_lane():
 
 
 # ---------------------------------------------------------------------------
-# the bit-identity contract: sharded == vmap
+# the bit-identity contract: sharded == vmap on each device's block
 # ---------------------------------------------------------------------------
 
 def test_sharded_matches_vmap_uneven_lanes(env_pol):
@@ -128,8 +161,14 @@ def test_sharded_matches_vmap_uneven_lanes(env_pol):
     rs = sweep(env, pol, scens, key, 2, mode="sharded")
     assert rs.mode == "sharded" and rs.n_devices == N_DEV
     assert rv.n_devices == 1
+    ref = _vmap_per_block(env, pol, scens, key, 2, default_sweep_mesh())
+    if N_DEV == 1:
+        for i in range(len(scens)):
+            assert _hist_equal(rv.scenario_history(i),
+                               rs.scenario_history(i)), i
     for i in range(len(scens)):
-        assert _hist_equal(rv.scenario_history(i), rs.scenario_history(i)), i
+        assert _hist_equal(jax.tree.map(lambda x: x[i], ref),
+                           rs.scenario_history(i)), i
 
 
 def test_sharded_matches_vmap_on_golden_scenarios():
@@ -162,8 +201,12 @@ def test_sharded_replicate_path_and_mc_sharding(env_pol):
     key = jax.random.key(1)
     rv = sweep(env, pol, [s, s], key, mc, mode="vmap")
     rs = sweep(env, pol, [s, s], key, mc, mode="sharded")
+    ref = _vmap_per_block(env, pol, [s, s], key, mc, default_sweep_mesh())
     for i in range(2):
-        assert _hist_equal(rv.scenario_history(i), rs.scenario_history(i))
+        if N_DEV == 1:
+            assert _hist_equal(rv.scenario_history(i),
+                               rs.scenario_history(i))
+        assert _hist_equal(ref, rs.scenario_history(i))
     assert _hist_equal(rs.scenario_history(0), rs.scenario_history(1))
 
 
@@ -173,11 +216,12 @@ def test_sharded_lane_x_mc_mesh(env_pol):
     scens = grid(channel=RayleighChannel(), noise_sigma=[1e-3, 1e-2], **SMALL)
     mesh = make_sweep_mesh(lane_shards=N_DEV // 2, mc_shards=2)
     key = jax.random.key(2)
-    rv = sweep(env, pol, scens, key, 2, mode="vmap")
+    ref = _vmap_per_block(env, pol, scens, key, 2, mesh)
     rs = sweep(env, pol, scens, key, 2, mode="sharded", mesh=mesh)
     assert rs.n_devices == mesh.size
     for i in range(len(scens)):
-        assert _hist_equal(rv.scenario_history(i), rs.scenario_history(i))
+        assert _hist_equal(jax.tree.map(lambda x: x[i], ref),
+                           rs.scenario_history(i))
 
 
 def test_sharded_mixed_partitions_async_accounting(env_pol):
@@ -310,8 +354,8 @@ def test_agent_mesh_divisibility_guard(env_pol):
 
 
 # ---------------------------------------------------------------------------
-# psum aggregation regression (jax<0.5 has no lax.axis_size — the shard_map
-# forms must run anyway)
+# psum aggregation regression (the shard_map forms must run on any mesh,
+# the degenerate one-device mesh included)
 # ---------------------------------------------------------------------------
 
 def _shard_grads(key, n_agents):
@@ -323,10 +367,10 @@ def _shard_grads(key, n_agents):
 
 
 def test_psum_aggregate_runs_on_axis_size_free_jax(key):
-    """Regression: local_gain/psum_aggregate used jax.lax.axis_size, which
-    the pinned jax doesn't have — the compat fallback must run on any mesh
-    (here the whole-device agents mesh, degenerate size 1 included)."""
-    from jax.experimental.shard_map import shard_map
+    """Regression: local_gain/psum_aggregate size the mesh axis with
+    jax.lax.axis_size and must run on any mesh (here the whole-device
+    agents mesh, degenerate size 1 included)."""
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = make_agent_mesh()
@@ -342,7 +386,7 @@ def test_psum_aggregate_runs_on_axis_size_free_jax(key):
             cfg, round_key, {k: v[0] for k, v in gl.items()}, ("agents",),
             n_agents=n),
         mesh=mesh, in_specs=({k: P("agents") for k in g},),
-        out_specs={k: P() for k in g}, check_rep=False,
+        out_specs={k: P() for k in g}, check_vma=False,
     )(g)
 
     key_h, _ = jax.random.split(round_key)
@@ -358,7 +402,7 @@ def test_psum_aggregate_stacked_local_agent_stacks(key):
     """The multi-agent-per-shard form: global gain indices are
     shard*n_local+j, so a 1-shard mesh with the full stack must equal
     aggregate_stacked fed the fold_in gain stream explicitly."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     n_agents = 6
@@ -375,7 +419,7 @@ def test_psum_aggregate_stacked_local_agent_stacks(key):
 
     out, h = shard_map(
         local, mesh=mesh, in_specs=({k: P() for k in g},),
-        out_specs=({k: P() for k in g}, P()), check_rep=False,
+        out_specs=({k: P() for k in g}, P()), check_vma=False,
     )(g)
     assert h.shape == (n_agents,)
 

@@ -20,7 +20,8 @@ Regenerating after an INTENTIONAL numerical change:
 
 then inspect the JSON diff — every changed number is a behaviour change the
 PR must justify.  ``tests/test_distribute.py`` reuses these scenarios to
-hold ``mode="sharded"`` bit-identical to ``mode="vmap"``.
+hold ``mode="sharded"`` bit-identical to ``mode="vmap"`` (on one device;
+across devices, see ``repro.core.distribute``).
 """
 import functools
 import json

@@ -137,7 +137,7 @@ def test_property_zero_grads_yield_pure_noise(n_agents, sigma, gain):
 
 def test_psum_aggregate_matches_stacked(key):
     """Form 2 (shard_map psum) == Form 1 (stacked) given the same gains."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     n = jax.local_device_count()
@@ -153,7 +153,7 @@ def test_psum_aggregate_matches_stacked(key):
 
     out = shard_map(
         local, mesh=mesh, in_specs=({k: P("data") for k in g},),
-        out_specs={k: P() for k in g}, check_rep=False,
+        out_specs={k: P() for k in g}, check_vma=False,
     )(g)
 
     key_h, _ = jax.random.split(round_key)

@@ -222,7 +222,7 @@ def test_stacked_equals_weighted_loss_form(policy):
 def test_psum_equals_stacked_under_power_control(policy):
     """Form 2 (shard_map psum) == Form 1 given the same gains, with
     power_control and update_scale set at the same time."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P  # noqa: F401
 
     n = jax.local_device_count()
@@ -239,7 +239,7 @@ def test_psum_equals_stacked_under_power_control(policy):
 
     out = shard_map(
         local, mesh=mesh, in_specs=({k: P("data") for k in g},),
-        out_specs={k: P() for k in g}, check_rep=False,
+        out_specs={k: P() for k in g}, check_vma=False,
     )(g)
 
     key_h, _ = jax.random.split(round_key)
