@@ -14,10 +14,13 @@ machine-enforce the no-recompile-per-call invariant in CI.
 
 The listener must be registered once per process; ``jax.monitoring``
 offers no unregister, so the counter toggles an "active" flag instead.
+It also keeps a running total, :func:`backend_compiles`, that callers
+difference around a region of their own (``RoundService.commit`` records
+its commit's compiles that way).
 """
 from __future__ import annotations
 
-_COMPILE_COUNTER = {"active": False, "count": 0}
+_COMPILE_COUNTER = {"active": False, "count": 0, "total": 0}
 _LISTENER_REGISTERED = False
 _EAGER_HELPERS_WARMED = False
 
@@ -25,8 +28,10 @@ _EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 def _on_event_duration(event: str, *args, **kwargs) -> None:
-    if _COMPILE_COUNTER["active"] and event == _EVENT:
-        _COMPILE_COUNTER["count"] += 1
+    if event == _EVENT:
+        _COMPILE_COUNTER["total"] += 1
+        if _COMPILE_COUNTER["active"]:
+            _COMPILE_COUNTER["count"] += 1
 
 
 def _ensure_listener() -> None:
@@ -37,6 +42,13 @@ def _ensure_listener() -> None:
 
     jax.monitoring.register_event_duration_secs_listener(_on_event_duration)
     _LISTENER_REGISTERED = True
+
+
+def backend_compiles() -> int:
+    """Backend compiles in this process since the listener was first
+    registered (this call registers it if no one has)."""
+    _ensure_listener()
+    return _COMPILE_COUNTER["total"]
 
 
 class CompileCounter:

@@ -238,7 +238,10 @@ class KeyReuseRule(Rule):
                 state.merge(body_state)
                 continue
             if isinstance(stmt, (ast.With, ast.AsyncWith)):
-                self._consume_in_stmt(stmt, state, findings, ctx)
+                # the context expressions only: the body is scanned below
+                for item in stmt.items:
+                    self._consume_in_stmt(item.context_expr, state,
+                                          findings, ctx)
                 state.refresh(_assigned_names(stmt))
                 self._scan_block(stmt.body, state, findings, ctx)
                 continue

@@ -33,6 +33,8 @@ from repro.service.participation import ParticipationConfig, ServiceState
 from repro.service.staleness import StalenessConfig
 from repro.telemetry.probes import RoundTelemetry, TelemetryConfig
 from repro.telemetry import probes as _probes
+from repro.telemetry import scopes
+from repro.telemetry.scopes import EPILOGUE, ROLLOUT, UPLINK
 from repro.utils.tree import tree_global_norm_sq
 
 PyTree = Any
@@ -76,6 +78,12 @@ def _active_telemetry(
                             and telemetry.participation):
         return telemetry
     return None
+
+
+def _mean_scope(noisy: bool):
+    """The exact mean's named scope: the update itself on an exact uplink,
+    a metric only (``grad_sq``) on a noisy one."""
+    return jax.named_scope(EPILOGUE if noisy else UPLINK)
 
 
 def _estimator_grad(cfg: FedPGConfig):
@@ -183,8 +191,9 @@ def make_round_fn(
         check_agent_count(env, cfg.n_agents)
 
     def round_fn(theta: PyTree, key: jax.Array):
-        key_samp, key_chan = jax.random.split(key)
-        agent_keys = jax.random.split(key_samp, cfg.n_agents)
+        with jax.named_scope(ROLLOUT):
+            key_samp, key_chan = jax.random.split(key)
+            agent_keys = jax.random.split(key_samp, cfg.n_agents)
 
         # --- local sampling + estimation (parallel across agents) --------
         def agent_grad(k, lane_params):
@@ -196,36 +205,38 @@ def make_round_fn(
         grads, trajs = jax.vmap(agent_grad)(agent_keys, lane_stacks)  # N axis
 
         # --- uplink + server update --------------------------------------
-        mean_grad = ota.aggregate(grads, None)[0]  # also the grad_sq metric
+        with _mean_scope(ota_cfg is not None):
+            mean_grad = ota.aggregate(grads, None)[0]  # also grad_sq's
         if ota_cfg is None:
-            gain_mean = jnp.ones(())
-            theta_next = jax.tree.map(
-                lambda p, u: p - cfg.alpha * u, theta, mean_grad)
+            with jax.named_scope(UPLINK):
+                theta_next = jax.tree.map(
+                    lambda p, u: p - cfg.alpha * u, theta, mean_grad)
         else:
             theta_next, h = ota.aggregate_apply(
                 grads, ota_cfg, theta, key=key_chan, alpha=cfg.alpha,
                 backend=ota_backend)
-            gain_mean = jnp.mean(h)
 
         # --- metrics ------------------------------------------------------
-        reward = empirical_reward(trajs, cfg.gamma)
-        grad_sq = tree_global_norm_sq(mean_grad)
-        if telem is None:
-            return theta_next, (reward, grad_sq, gain_mean)
+        with jax.named_scope(EPILOGUE):
+            gain_mean = jnp.ones(()) if ota_cfg is None else jnp.mean(h)
+            reward = empirical_reward(trajs, cfg.gamma)
+            grad_sq = tree_global_norm_sq(mean_grad)
+            if telem is None:
+                return theta_next, (reward, grad_sq, gain_mean)
 
-        # --- telemetry probes (in-jit, only when requested) ---------------
-        if ota_cfg is None:
-            gains = jnp.ones((cfg.n_agents,))
-            update_norm = jnp.sqrt(grad_sq)
-        else:
-            gains = h
-            update_norm = jnp.sqrt(tree_global_norm_sq(jax.tree.map(
-                jnp.subtract, theta, theta_next))) / cfg.alpha
-        probes = _probes.stacked_round_probes(
-            telem, grads_stacked=grads, gains=gains, ota_cfg=ota_cfg,
-            n_agents=cfg.n_agents, gain_mean=gain_mean,
-            update_norm=update_norm)
-        return theta_next, (reward, grad_sq, gain_mean, probes)
+            # --- telemetry probes (in-jit, only when requested) -----------
+            if ota_cfg is None:
+                gains = jnp.ones((cfg.n_agents,))
+                update_norm = jnp.sqrt(grad_sq)
+            else:
+                gains = h
+                update_norm = jnp.sqrt(tree_global_norm_sq(jax.tree.map(
+                    jnp.subtract, theta, theta_next))) / cfg.alpha
+            probes = _probes.stacked_round_probes(
+                telem, grads_stacked=grads, gains=gains, ota_cfg=ota_cfg,
+                n_agents=cfg.n_agents, gain_mean=gain_mean,
+                update_norm=update_norm)
+            return theta_next, (reward, grad_sq, gain_mean, probes)
 
     if part is None:
         return round_fn
@@ -234,14 +245,17 @@ def make_round_fn(
 
     def service_round(state: ServiceState, key: jax.Array):
         theta = state.theta
-        key_samp, key_chan = jax.random.split(key)
-        agent_keys = jax.random.split(key_samp, cfg.n_agents)
-        ids = jnp.arange(cfg.n_agents, dtype=jnp.int32)
-        mask = svc_participation.round_mask(
-            part, state.part_key, state.sched_key, state.round_idx, ids,
-            cfg.n_agents)
-        mf = mask.astype(jnp.float32)
-        count_p = jnp.sum(mf)
+        noisy = ota_cfg is not None
+        with jax.named_scope(ROLLOUT):
+            key_samp, key_chan = jax.random.split(key)
+            agent_keys = jax.random.split(key_samp, cfg.n_agents)
+        with jax.named_scope(UPLINK):
+            ids = jnp.arange(cfg.n_agents, dtype=jnp.int32)
+            mask = svc_participation.round_mask(
+                part, state.part_key, state.sched_key, state.round_idx, ids,
+                cfg.n_agents)
+            mf = mask.astype(jnp.float32)
+            count_p = jnp.sum(mf)
 
         # rollouts run for every agent (same per-agent keys as the plain
         # round: the realised trajectories of a participant are identical
@@ -254,71 +268,76 @@ def make_round_fn(
 
         lane_stacks = dict(env.params) if hetero else {}
         grads, trajs = jax.vmap(agent_grad)(agent_keys, lane_stacks)
-        gm = svc_participation.mask_agent_axis(grads, mask)
 
-        if stale_cfg is not None:
-            rw = svc_staleness.replay_weights(stale_cfg, mask, state.stale.age)
-            w_replay, _, stale_age = svc_staleness.stats(
-                stale_cfg, mask, state.stale.age)
-            ssum = svc_staleness.replay_sum_stacked(state.stale, rw)
-            stale_next = svc_staleness.advance(
-                stale_cfg, state.stale, mask, grads)
-        else:
-            w_replay = jnp.zeros((), jnp.float32)
-            ssum = stale_next = stale_age = None
+        with jax.named_scope(UPLINK):
+            gm = svc_participation.mask_agent_axis(grads, mask)
+            if stale_cfg is not None:
+                rw = svc_staleness.replay_weights(stale_cfg, mask,
+                                                  state.stale.age)
+                w_replay, _, stale_age = svc_staleness.stats(
+                    stale_cfg, mask, state.stale.age)
+                ssum = svc_staleness.replay_sum_stacked(state.stale, rw)
+                stale_next = svc_staleness.advance(
+                    stale_cfg, state.stale, mask, grads)
+            else:
+                w_replay = jnp.zeros((), jnp.float32)
+                ssum = stale_next = stale_age = None
 
-        w_real = count_p + w_replay
-        w_norm = w_real if part.debias == "realized" else jnp.asarray(
-            svc_participation.expected_count(part, cfg.n_agents), jnp.float32)
-        inv_w = svc_participation.safe_inv(w_norm)
-        pf = svc_participation.participation_factor(cfg.n_agents, w_norm)
+            w_real = count_p + w_replay
+            w_norm = w_real if part.debias == "realized" else jnp.asarray(
+                svc_participation.expected_count(part, cfg.n_agents),
+                jnp.float32)
+            inv_w = svc_participation.safe_inv(w_norm)
+            pf = svc_participation.participation_factor(cfg.n_agents, w_norm)
 
-        gsum = jax.tree.map(lambda g: jnp.sum(g, axis=0), gm)
-        if ssum is not None:
-            gsum = jax.tree.map(jnp.add, gsum, ssum)
-        mean_grad = jax.tree.map(lambda s: s * inv_w, gsum)
-
-        if ota_cfg is None:
-            gain_mean = jnp.ones(())
-            update = mean_grad
-        else:
-            key_h, _ = jax.random.split(key_chan)
-            h = ota.sample_gains(ota_cfg, key_h, cfg.n_agents)
-            hm = jnp.where(mask, h, jnp.zeros_like(h))
-            # passing key_chan reproduces the plain round's AWGN stream:
-            # aggregate re-splits it to the same key_n internally
-            u_fresh = ota.aggregate(gm, ota_cfg, key=key_chan, gains=hm,
-                                    backend=ota_backend)[0]
-            update = jax.tree.map(lambda u: u * pf, u_fresh)
+        with _mean_scope(noisy):
+            gsum = jax.tree.map(lambda g: jnp.sum(g, axis=0), gm)
             if ssum is not None:
-                update = jax.tree.map(
-                    lambda u, s: u + s * inv_w, update, ssum)
-            gain_mean = jnp.sum(hm) * svc_participation.safe_inv(count_p)
-        theta_next = jax.tree.map(
-            lambda p, u: p - cfg.alpha * u, theta, update)
+                gsum = jax.tree.map(jnp.add, gsum, ssum)
+            mean_grad = jax.tree.map(lambda s: s * inv_w, gsum)
+
+        with jax.named_scope(UPLINK):
+            if not noisy:
+                update = mean_grad
+            else:
+                key_h, _ = jax.random.split(key_chan)
+                h = ota.sample_gains(ota_cfg, key_h, cfg.n_agents)
+                hm = jnp.where(mask, h, jnp.zeros_like(h))
+                # passing key_chan reproduces the plain round's AWGN
+                # stream: aggregate re-splits it to the same key_n internally
+                u_fresh = ota.aggregate(gm, ota_cfg, key=key_chan, gains=hm,
+                                        backend=ota_backend)[0]
+                update = jax.tree.map(lambda u: u * pf, u_fresh)
+                if ssum is not None:
+                    update = jax.tree.map(
+                        lambda u, s: u + s * inv_w, update, ssum)
+            theta_next = jax.tree.map(
+                lambda p, u: p - cfg.alpha * u, theta, update)
+            state_next = state._replace(theta=theta_next,
+                                        round_idx=state.round_idx + 1,
+                                        stale=stale_next)
 
         # metrics over the agents that actually made the round
-        returns = discounted_return(trajs.losses, cfg.gamma)
-        reward = -jnp.sum(jnp.where(mask[:, None], returns, 0.0)) \
-            * svc_participation.safe_inv(count_p) / cfg.batch_m
-        grad_sq = tree_global_norm_sq(mean_grad)
+        with jax.named_scope(EPILOGUE):
+            gain_mean = jnp.sum(hm) * svc_participation.safe_inv(count_p) \
+                if noisy else jnp.ones(())
+            returns = discounted_return(trajs.losses, cfg.gamma)
+            reward = -jnp.sum(jnp.where(mask[:, None], returns, 0.0)) \
+                * svc_participation.safe_inv(count_p) / cfg.batch_m
+            grad_sq = tree_global_norm_sq(mean_grad)
+            if telem is None:
+                return state_next, (reward, grad_sq, gain_mean)
 
-        state_next = state._replace(theta=theta_next,
-                                    round_idx=state.round_idx + 1,
-                                    stale=stale_next)
-        if telem is None:
-            return state_next, (reward, grad_sq, gain_mean)
-
-        probes = _probes.stacked_round_probes(
-            telem, grads_stacked=gm, gains=hm if ota_cfg is not None else mf,
-            ota_cfg=ota_cfg, n_agents=cfg.n_agents, gain_mean=gain_mean,
-            update_norm=jnp.sqrt(tree_global_norm_sq(update)))
-        probes = _probes.participation_probes(
-            telem, probes, rate_realized=count_p / cfg.n_agents,
-            rate_expected=svc_participation.expected_count(
-                part, cfg.n_agents) / cfg.n_agents,
-            staleness_mean=stale_age)
-        return state_next, (reward, grad_sq, gain_mean, probes)
+            probes = _probes.stacked_round_probes(
+                telem, grads_stacked=gm, gains=hm if noisy else mf,
+                ota_cfg=ota_cfg, n_agents=cfg.n_agents, gain_mean=gain_mean,
+                update_norm=jnp.sqrt(tree_global_norm_sq(update)))
+            probes = _probes.participation_probes(
+                telem, probes, rate_realized=count_p / cfg.n_agents,
+                rate_expected=svc_participation.expected_count(
+                    part, cfg.n_agents) / cfg.n_agents,
+                staleness_mean=stale_age)
+            return state_next, (reward, grad_sq, gain_mean, probes)
 
     return service_round
 
@@ -358,22 +377,24 @@ def _make_streamed_round_fn(
         telemetry.grad_norms or telemetry.dispersion)
 
     def round_fn(theta: PyTree, key: jax.Array):
-        key_samp, key_chan = jax.random.split(key)
-        agent_keys = jax.random.split(key_samp, cfg.n_agents)
-        lane_stacks = dict(env.params) if hetero else {}
-        xs = {
-            "keys": ota.block_view(
-                ota.pad_agent_axis(agent_keys, pad), n_blocks, block),
-            "stacks": ota.block_view(
-                ota.pad_agent_axis(lane_stacks, pad), n_blocks, block),
-            "valid": ota.block_valid_mask(cfg.n_agents, n_blocks, block),
-        }
+        with jax.named_scope(ROLLOUT):
+            key_samp, key_chan = jax.random.split(key)
+            agent_keys = jax.random.split(key_samp, cfg.n_agents)
+            lane_stacks = dict(env.params) if hetero else {}
+            xs = {
+                "keys": ota.block_view(
+                    ota.pad_agent_axis(agent_keys, pad), n_blocks, block),
+                "stacks": ota.block_view(
+                    ota.pad_agent_axis(lane_stacks, pad), n_blocks, block),
+                "valid": ota.block_valid_mask(cfg.n_agents, n_blocks, block),
+            }
         if noisy:
-            key_h, key_n = jax.random.split(key_chan)
-            h = ota.sample_gains(ota_cfg, key_h, cfg.n_agents)
-            hp = jnp.concatenate([h, jnp.zeros((pad,), h.dtype)]) \
-                if pad else h
-            xs["gains"] = hp.reshape(n_blocks, block)
+            with jax.named_scope(UPLINK):
+                key_h, key_n = jax.random.split(key_chan)
+                h = ota.sample_gains(ota_cfg, key_h, cfg.n_agents)
+                hp = jnp.concatenate([h, jnp.zeros((pad,), h.dtype)]) \
+                    if pad else h
+                xs["gains"] = hp.reshape(n_blocks, block)
 
         def agent_grad(k, lane_params):
             e = env.lane(lane_params) if hetero else env
@@ -382,62 +403,71 @@ def _make_streamed_round_fn(
 
         def block_body(carry, x):
             grads_b, trajs_b = jax.vmap(agent_grad)(x["keys"], x["stacks"])
-            gsum = ota.stream_fold_block(carry[0], grads_b, None, x["valid"])
-            ys = {"returns": discounted_return(trajs_b.losses, cfg.gamma)}
-            if want_norms:
-                ys["norms_sq"] = sum(
-                    _probes._leaf_norms(g, block)
-                    for g in jax.tree.leaves(grads_b))
+            with _mean_scope(noisy):
+                gsum = ota.stream_fold_block(carry[0], grads_b, None,
+                                             x["valid"])
+            with jax.named_scope(EPILOGUE):
+                ys = {"returns": discounted_return(trajs_b.losses,
+                                                   cfg.gamma)}
+                if want_norms:
+                    ys["norms_sq"] = sum(
+                        _probes._leaf_norms(g, block)
+                        for g in jax.tree.leaves(grads_b))
             if not noisy:
                 return (gsum,), ys
-            gb = jax.tree.map(lambda a: a.astype(jnp.float32), grads_b) \
-                if pallas else grads_b
-            v = ota.stream_fold_block(carry[1], gb, x["gains"], x["valid"],
-                                      wire_dtype=wire_dt)
+            with jax.named_scope(UPLINK):
+                gb = jax.tree.map(lambda a: a.astype(jnp.float32),
+                                  grads_b) if pallas else grads_b
+                v = ota.stream_fold_block(carry[1], gb, x["gains"],
+                                          x["valid"], wire_dtype=wire_dt)
             return (gsum, v), ys
 
-        carry0 = (jax.tree.map(jnp.zeros_like, theta),)
+        with _mean_scope(noisy):
+            carry0 = (jax.tree.map(jnp.zeros_like, theta),)
         if noisy:
-            vdt = (lambda p: jnp.float32) if pallas else (lambda p: p.dtype)
-            carry0 += (jax.tree.map(
-                lambda p: jnp.zeros(p.shape, vdt(p)), theta),)
+            with jax.named_scope(UPLINK):
+                vdt = (lambda p: jnp.float32) if pallas \
+                    else (lambda p: p.dtype)
+                carry0 += (jax.tree.map(
+                    lambda p: jnp.zeros(p.shape, vdt(p)), theta),)
         carry, ys = jax.lax.scan(block_body, carry0, xs)
 
-        # per-agent scalars come back (n_blocks, block, ...); restore the
-        # absolute agent order and drop the phantom tail before reducing
-        # with the exact ops the unblocked round uses.
-        returns = ys["returns"].reshape(
-            (n_blocks * block,) + ys["returns"].shape[2:])[:cfg.n_agents]
-        reward = -jnp.mean(returns)
-        mean_grad = jax.tree.map(lambda s: s / cfg.n_agents, carry[0])
-        grad_sq = tree_global_norm_sq(mean_grad)
-
+        with _mean_scope(noisy):
+            mean_grad = jax.tree.map(lambda s: s / cfg.n_agents, carry[0])
         if not noisy:
-            gain_mean = jnp.ones(())
-            theta_next = jax.tree.map(
-                lambda p, u: p - cfg.alpha * u, theta, mean_grad)
+            with jax.named_scope(UPLINK):
+                theta_next = jax.tree.map(
+                    lambda p, u: p - cfg.alpha * u, theta, mean_grad)
         else:
             theta_next = ota.stream_finalize_apply(
                 ota_cfg, key_n, carry[1], theta, cfg.alpha, cfg.n_agents,
                 backend="pallas" if pallas else "xla")
-            gain_mean = jnp.mean(h)
 
-        if telemetry is None:
-            return theta_next, (reward, grad_sq, gain_mean)
+        with jax.named_scope(EPILOGUE):
+            # per-agent scalars come back (n_blocks, block, ...); restore
+            # the absolute agent order and drop the phantom tail before
+            # reducing with the exact ops the unblocked round uses.
+            returns = ys["returns"].reshape(
+                (n_blocks * block,) + ys["returns"].shape[2:])[:cfg.n_agents]
+            reward = -jnp.mean(returns)
+            grad_sq = tree_global_norm_sq(mean_grad)
+            gain_mean = jnp.mean(h) if noisy else jnp.ones(())
+            if telemetry is None:
+                return theta_next, (reward, grad_sq, gain_mean)
 
-        if not noisy:
-            update_norm = jnp.sqrt(grad_sq)
-        else:
-            update_norm = jnp.sqrt(tree_global_norm_sq(jax.tree.map(
-                jnp.subtract, theta, theta_next))) / cfg.alpha
-        norms_sq = ys["norms_sq"].reshape(-1)[:cfg.n_agents] \
-            if want_norms else None
-        probes = _probes.streamed_round_probes(
-            telemetry, v=carry[1] if noisy else None, norms_sq=norms_sq,
-            ota_cfg=ota_cfg, n_agents=cfg.n_agents,
-            param_dim=sum(int(p.size) for p in jax.tree.leaves(theta)),
-            gain_mean=gain_mean, update_norm=update_norm)
-        return theta_next, (reward, grad_sq, gain_mean, probes)
+            if not noisy:
+                update_norm = jnp.sqrt(grad_sq)
+            else:
+                update_norm = jnp.sqrt(tree_global_norm_sq(jax.tree.map(
+                    jnp.subtract, theta, theta_next))) / cfg.alpha
+            norms_sq = ys["norms_sq"].reshape(-1)[:cfg.n_agents] \
+                if want_norms else None
+            probes = _probes.streamed_round_probes(
+                telemetry, v=carry[1] if noisy else None, norms_sq=norms_sq,
+                ota_cfg=ota_cfg, n_agents=cfg.n_agents,
+                param_dim=sum(int(p.size) for p in jax.tree.leaves(theta)),
+                gain_mean=gain_mean, update_norm=update_norm)
+            return theta_next, (reward, grad_sq, gain_mean, probes)
 
     part, stale_cfg = participation, staleness
     if part is None:
@@ -449,50 +479,57 @@ def _make_streamed_round_fn(
         # block sizes, so the streamed service round inherits the blocked
         # round's bitwise block-invariance.
         theta = state.theta
-        key_samp, key_chan = jax.random.split(key)
-        agent_keys = jax.random.split(key_samp, cfg.n_agents)
-        lane_stacks = dict(env.params) if hetero else {}
-        ids = jnp.arange(cfg.n_agents, dtype=jnp.int32)
-        mask = svc_participation.round_mask(
-            part, state.part_key, state.sched_key, state.round_idx, ids,
-            cfg.n_agents)
-        mf = mask.astype(jnp.float32)
-        count_p = jnp.sum(mf)
+        with jax.named_scope(ROLLOUT):
+            key_samp, key_chan = jax.random.split(key)
+            agent_keys = jax.random.split(key_samp, cfg.n_agents)
+            lane_stacks = dict(env.params) if hetero else {}
+        with jax.named_scope(UPLINK):
+            ids = jnp.arange(cfg.n_agents, dtype=jnp.int32)
+            mask = svc_participation.round_mask(
+                part, state.part_key, state.sched_key, state.round_idx, ids,
+                cfg.n_agents)
+            mf = mask.astype(jnp.float32)
+            count_p = jnp.sum(mf)
 
-        if stale_cfg is not None:
-            rw = svc_staleness.replay_weights(stale_cfg, mask, state.stale.age)
-            w_replay, _, stale_age = svc_staleness.stats(
-                stale_cfg, mask, state.stale.age)
-        else:
-            rw = None
-            w_replay = jnp.zeros((), jnp.float32)
-            stale_age = None
-        w_real = count_p + w_replay
-        w_norm = w_real if part.debias == "realized" else jnp.asarray(
-            svc_participation.expected_count(part, cfg.n_agents), jnp.float32)
-        inv_w = svc_participation.safe_inv(w_norm)
+            if stale_cfg is not None:
+                rw = svc_staleness.replay_weights(stale_cfg, mask,
+                                                  state.stale.age)
+                w_replay, _, stale_age = svc_staleness.stats(
+                    stale_cfg, mask, state.stale.age)
+            else:
+                rw = None
+                w_replay = jnp.zeros((), jnp.float32)
+                stale_age = None
+            w_real = count_p + w_replay
+            w_norm = w_real if part.debias == "realized" else jnp.asarray(
+                svc_participation.expected_count(part, cfg.n_agents),
+                jnp.float32)
+            inv_w = svc_participation.safe_inv(w_norm)
 
         def _pad_row(a):
             return jnp.concatenate([a, jnp.zeros((pad,), a.dtype)]) \
                 if pad else a
 
-        xs = {
-            "keys": ota.block_view(
-                ota.pad_agent_axis(agent_keys, pad), n_blocks, block),
-            "stacks": ota.block_view(
-                ota.pad_agent_axis(lane_stacks, pad), n_blocks, block),
-            "valid": ota.block_valid_mask(cfg.n_agents, n_blocks, block),
-            "pmask": _pad_row(mf).reshape(n_blocks, block),
-        }
-        if noisy:
-            key_h, key_n = jax.random.split(key_chan)
-            h = ota.sample_gains(ota_cfg, key_h, cfg.n_agents)
-            hm = jnp.where(mask, h, jnp.zeros_like(h))
-            xs["gains"] = _pad_row(hm).reshape(n_blocks, block)
-        if stale_cfg is not None:
-            xs["stale"] = ota.block_view(
-                ota.pad_agent_axis(state.stale.grads, pad), n_blocks, block)
-            xs["rw"] = _pad_row(rw).reshape(n_blocks, block)
+        with jax.named_scope(ROLLOUT):
+            xs = {
+                "keys": ota.block_view(
+                    ota.pad_agent_axis(agent_keys, pad), n_blocks, block),
+                "stacks": ota.block_view(
+                    ota.pad_agent_axis(lane_stacks, pad), n_blocks, block),
+                "valid": ota.block_valid_mask(cfg.n_agents, n_blocks, block),
+            }
+        with jax.named_scope(UPLINK):
+            xs["pmask"] = _pad_row(mf).reshape(n_blocks, block)
+            if noisy:
+                key_h, key_n = jax.random.split(key_chan)
+                h = ota.sample_gains(ota_cfg, key_h, cfg.n_agents)
+                hm = jnp.where(mask, h, jnp.zeros_like(h))
+                xs["gains"] = _pad_row(hm).reshape(n_blocks, block)
+            if stale_cfg is not None:
+                xs["stale"] = ota.block_view(
+                    ota.pad_agent_axis(state.stale.grads, pad), n_blocks,
+                    block)
+                xs["rw"] = _pad_row(rw).reshape(n_blocks, block)
 
         def agent_grad(k, lane_params):
             e = env.lane(lane_params) if hetero else env
@@ -501,97 +538,107 @@ def _make_streamed_round_fn(
 
         def block_body(carry, x):
             grads_b, trajs_b = jax.vmap(agent_grad)(x["keys"], x["stacks"])
-            out = {"gsum": ota.stream_fold_block(
-                carry["gsum"], grads_b, x["pmask"], x["valid"])}
-            ys = {"returns": discounted_return(trajs_b.losses, cfg.gamma)}
-            if want_norms:
-                ys["norms_sq"] = sum(
-                    _probes._leaf_norms(g, block)
-                    for g in jax.tree.leaves(grads_b))
-            if stale_cfg is not None:
-                out["ssum"] = ota.stream_fold_block(
-                    carry["ssum"], x["stale"], x["rw"], x["valid"])
-                pm = x["pmask"] > 0
-                ys["stale_new"] = jax.tree.map(
-                    lambda fresh, old: jnp.where(
-                        pm.reshape((-1,) + (1,) * (fresh.ndim - 1)),
-                        fresh, old),
-                    grads_b, x["stale"])
-            if noisy:
-                gb = jax.tree.map(
-                    lambda a: a.astype(jnp.float32), grads_b) \
-                    if pallas else grads_b
-                out["v"] = ota.stream_fold_block(
-                    carry["v"], gb, x["gains"], x["valid"],
-                    wire_dtype=wire_dt)
+            with _mean_scope(noisy):
+                out = {"gsum": ota.stream_fold_block(
+                    carry["gsum"], grads_b, x["pmask"], x["valid"])}
+            with jax.named_scope(EPILOGUE):
+                ys = {"returns": discounted_return(trajs_b.losses,
+                                                   cfg.gamma)}
+                if want_norms:
+                    ys["norms_sq"] = sum(
+                        _probes._leaf_norms(g, block)
+                        for g in jax.tree.leaves(grads_b))
+            with jax.named_scope(UPLINK):
+                if stale_cfg is not None:
+                    out["ssum"] = ota.stream_fold_block(
+                        carry["ssum"], x["stale"], x["rw"], x["valid"])
+                    pm = x["pmask"] > 0
+                    ys["stale_new"] = jax.tree.map(
+                        lambda fresh, old: jnp.where(
+                            pm.reshape((-1,) + (1,) * (fresh.ndim - 1)),
+                            fresh, old),
+                        grads_b, x["stale"])
+                if noisy:
+                    gb = jax.tree.map(
+                        lambda a: a.astype(jnp.float32), grads_b) \
+                        if pallas else grads_b
+                    out["v"] = ota.stream_fold_block(
+                        carry["v"], gb, x["gains"], x["valid"],
+                        wire_dtype=wire_dt)
             return out, ys
 
-        carry0 = {"gsum": jax.tree.map(jnp.zeros_like, theta)}
-        if stale_cfg is not None:
-            carry0["ssum"] = jax.tree.map(jnp.zeros_like, theta)
-        if noisy:
-            vdt = (lambda p: jnp.float32) if pallas else (lambda p: p.dtype)
-            carry0["v"] = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, vdt(p)), theta)
+        with _mean_scope(noisy):
+            carry0 = {"gsum": jax.tree.map(jnp.zeros_like, theta)}
+        with jax.named_scope(UPLINK):
+            if stale_cfg is not None:
+                carry0["ssum"] = jax.tree.map(jnp.zeros_like, theta)
+            if noisy:
+                vdt = (lambda p: jnp.float32) if pallas \
+                    else (lambda p: p.dtype)
+                carry0["v"] = jax.tree.map(
+                    lambda p: jnp.zeros(p.shape, vdt(p)), theta)
         carry, ys = jax.lax.scan(block_body, carry0, xs)
 
-        gsum = carry["gsum"]
-        if stale_cfg is not None:
-            gsum = jax.tree.map(jnp.add, gsum, carry["ssum"])
-        mean_grad = jax.tree.map(lambda s: s * inv_w, gsum)
-        grad_sq = tree_global_norm_sq(mean_grad)
-
-        if not noisy:
-            gain_mean = jnp.ones(())
-            update = mean_grad
-        else:
-            update = ota.stream_finalize(
-                ota_cfg, key_n, carry["v"], cfg.n_agents,
-                backend="pallas" if pallas else "xla", n_eff=w_norm)
+        with _mean_scope(noisy):
+            gsum = carry["gsum"]
             if stale_cfg is not None:
-                update = jax.tree.map(
-                    lambda u, s: u + s * inv_w, update, carry["ssum"])
-            gain_mean = jnp.sum(hm) * svc_participation.safe_inv(count_p)
-        theta_next = jax.tree.map(
-            lambda p, u: p - cfg.alpha * u, theta, update)
+                gsum = jax.tree.map(jnp.add, gsum, carry["ssum"])
+            mean_grad = jax.tree.map(lambda s: s * inv_w, gsum)
 
-        returns = ys["returns"].reshape(
-            (n_blocks * block,) + ys["returns"].shape[2:])[:cfg.n_agents]
-        reward = -jnp.sum(jnp.where(mask[:, None], returns, 0.0)) \
-            * svc_participation.safe_inv(count_p) / cfg.batch_m
+        with jax.named_scope(UPLINK):
+            if not noisy:
+                update = mean_grad
+            else:
+                update = ota.stream_finalize(
+                    ota_cfg, key_n, carry["v"], cfg.n_agents,
+                    backend="pallas" if pallas else "xla", n_eff=w_norm)
+                if stale_cfg is not None:
+                    update = jax.tree.map(
+                        lambda u, s: u + s * inv_w, update, carry["ssum"])
+            theta_next = jax.tree.map(
+                lambda p, u: p - cfg.alpha * u, theta, update)
 
-        if stale_cfg is not None:
-            buf = jax.tree.map(
-                lambda s: s.reshape(
-                    (n_blocks * block,) + s.shape[2:])[:cfg.n_agents],
-                ys["stale_new"])
-            age = jnp.where(mask, jnp.int32(1),
-                            jnp.minimum(state.stale.age + 1,
-                                        svc_staleness.AGE_NEVER))
-            stale_next = svc_staleness.StaleState(grads=buf, age=age)
-        else:
-            stale_next = None
-        state_next = state._replace(theta=theta_next,
-                                    round_idx=state.round_idx + 1,
-                                    stale=stale_next)
-        if telemetry is None:
-            return state_next, (reward, grad_sq, gain_mean)
+            if stale_cfg is not None:
+                buf = jax.tree.map(
+                    lambda s: s.reshape(
+                        (n_blocks * block,) + s.shape[2:])[:cfg.n_agents],
+                    ys["stale_new"])
+                age = jnp.where(mask, jnp.int32(1),
+                                jnp.minimum(state.stale.age + 1,
+                                            svc_staleness.AGE_NEVER))
+                stale_next = svc_staleness.StaleState(grads=buf, age=age)
+            else:
+                stale_next = None
+            state_next = state._replace(theta=theta_next,
+                                        round_idx=state.round_idx + 1,
+                                        stale=stale_next)
 
-        norms_sq = jnp.where(
-            mask, ys["norms_sq"].reshape(-1)[:cfg.n_agents], 0.0) \
-            if want_norms else None
-        probes = _probes.streamed_round_probes(
-            telemetry, v=carry["v"] if noisy else None, norms_sq=norms_sq,
-            ota_cfg=ota_cfg, n_agents=cfg.n_agents,
-            param_dim=sum(int(p.size) for p in jax.tree.leaves(theta)),
-            gain_mean=gain_mean,
-            update_norm=jnp.sqrt(tree_global_norm_sq(update)))
-        probes = _probes.participation_probes(
-            telemetry, probes, rate_realized=count_p / cfg.n_agents,
-            rate_expected=svc_participation.expected_count(
-                part, cfg.n_agents) / cfg.n_agents,
-            staleness_mean=stale_age)
-        return state_next, (reward, grad_sq, gain_mean, probes)
+        with jax.named_scope(EPILOGUE):
+            grad_sq = tree_global_norm_sq(mean_grad)
+            gain_mean = jnp.sum(hm) * svc_participation.safe_inv(count_p) \
+                if noisy else jnp.ones(())
+            returns = ys["returns"].reshape(
+                (n_blocks * block,) + ys["returns"].shape[2:])[:cfg.n_agents]
+            reward = -jnp.sum(jnp.where(mask[:, None], returns, 0.0)) \
+                * svc_participation.safe_inv(count_p) / cfg.batch_m
+            if telemetry is None:
+                return state_next, (reward, grad_sq, gain_mean)
+
+            norms_sq = jnp.where(
+                mask, ys["norms_sq"].reshape(-1)[:cfg.n_agents], 0.0) \
+                if want_norms else None
+            probes = _probes.streamed_round_probes(
+                telemetry, v=carry["v"] if noisy else None,
+                norms_sq=norms_sq, ota_cfg=ota_cfg, n_agents=cfg.n_agents,
+                param_dim=sum(int(p.size) for p in jax.tree.leaves(theta)),
+                gain_mean=gain_mean,
+                update_norm=jnp.sqrt(tree_global_norm_sq(update)))
+            probes = _probes.participation_probes(
+                telemetry, probes, rate_realized=count_p / cfg.n_agents,
+                rate_expected=svc_participation.expected_count(
+                    part, cfg.n_agents) / cfg.n_agents,
+                staleness_mean=stale_age)
+            return state_next, (reward, grad_sq, gain_mean, probes)
 
     return service_round
 
@@ -664,37 +711,42 @@ def _make_agent_sharded_round_fn(
             return grad_fn(policy, theta, traj, cfg.gamma), traj
 
         grads, trajs = jax.vmap(agent_grad)(agent_keys, lane_stacks)
-        mean_grad = ota.aggregate(
-            grads, None, axis=(axis_name,), n_agents=cfg.n_agents,
-            local_stack=True)[0]
+        with _mean_scope(ota_cfg is not None):
+            mean_grad = ota.aggregate(
+                grads, None, axis=(axis_name,), n_agents=cfg.n_agents,
+                local_stack=True)[0]
 
-        if ota_cfg is None:
-            update = mean_grad
-            gain_mean = jnp.ones(())
-        else:
-            update, h = ota.aggregate(
-                grads, ota_cfg, key=key_chan, axis=(axis_name,),
-                n_agents=cfg.n_agents, local_stack=True,
-                backend=ota_backend)
-            gain_mean = jax.lax.psum(jnp.sum(h), axis_name) / cfg.n_agents
-        theta_next = jax.tree.map(lambda p, u: p - cfg.alpha * u, theta, update)
+        with jax.named_scope(UPLINK):
+            if ota_cfg is None:
+                update = mean_grad
+            else:
+                update, h = ota.aggregate(
+                    grads, ota_cfg, key=key_chan, axis=(axis_name,),
+                    n_agents=cfg.n_agents, local_stack=True,
+                    backend=ota_backend)
+            theta_next = jax.tree.map(lambda p, u: p - cfg.alpha * u, theta,
+                                      update)
 
         # metrics: psum of local partial sums == the global means
-        r_local = -jnp.sum(discounted_return(trajs.losses, cfg.gamma))
-        reward = jax.lax.psum(r_local, axis_name) / (cfg.n_agents * cfg.batch_m)
-        grad_sq = tree_global_norm_sq(mean_grad)
-        if telemetry is None:
-            return theta_next, (reward, grad_sq, gain_mean)
+        with jax.named_scope(EPILOGUE):
+            gain_mean = jnp.ones(()) if ota_cfg is None else \
+                jax.lax.psum(jnp.sum(h), axis_name) / cfg.n_agents
+            r_local = -jnp.sum(discounted_return(trajs.losses, cfg.gamma))
+            reward = jax.lax.psum(r_local, axis_name) \
+                / (cfg.n_agents * cfg.batch_m)
+            grad_sq = tree_global_norm_sq(mean_grad)
+            if telemetry is None:
+                return theta_next, (reward, grad_sq, gain_mean)
 
-        # telemetry probes: psum/pmax reductions, replicated outputs
-        n_local = jax.tree.leaves(grads)[0].shape[0]
-        local_gains = h if ota_cfg is not None else jnp.ones((n_local,))
-        probes = _probes.sharded_round_probes(
-            telemetry, local_grads=grads, local_gains=local_gains,
-            ota_cfg=ota_cfg, n_agents=cfg.n_agents, axis_name=axis_name,
-            gain_mean=gain_mean,
-            update_norm=jnp.sqrt(tree_global_norm_sq(update)))
-        return theta_next, (reward, grad_sq, gain_mean, probes)
+            # telemetry probes: psum/pmax reductions, replicated outputs
+            n_local = jax.tree.leaves(grads)[0].shape[0]
+            local_gains = h if ota_cfg is not None else jnp.ones((n_local,))
+            probes = _probes.sharded_round_probes(
+                telemetry, local_grads=grads, local_gains=local_gains,
+                ota_cfg=ota_cfg, n_agents=cfg.n_agents, axis_name=axis_name,
+                gain_mean=gain_mean,
+                update_norm=jnp.sqrt(tree_global_norm_sq(update)))
+            return theta_next, (reward, grad_sq, gain_mean, probes)
 
     if agent_blocks is not None:
         nb, blk, bpad = ota.blocked_layout(n_local, agent_blocks)
@@ -710,80 +762,96 @@ def _make_agent_sharded_round_fn(
             traj = rollout_batch(e, policy, theta, k, cfg.horizon, cfg.batch_m)
             return grad_fn(policy, theta, traj, cfg.gamma), traj
 
-        _, valid_local = ota._sharded_stream_meta(
-            (axis_name,), n_local, cfg.n_agents)
-        if ota_cfg is not None:
-            key_h, key_n = jax.random.split(key_chan)
-            h, valid_local = ota.sharded_stream_gains(
-                ota_cfg, key_h, (axis_name,), n_local, cfg.n_agents)
+        noisy = ota_cfg is not None
+        with jax.named_scope(ROLLOUT):
+            _, valid_local = ota._sharded_stream_meta(
+                (axis_name,), n_local, cfg.n_agents)
+        if noisy:
+            with jax.named_scope(UPLINK):
+                key_h, key_n = jax.random.split(key_chan)
+                h, valid_local = ota.sharded_stream_gains(
+                    ota_cfg, key_h, (axis_name,), n_local, cfg.n_agents)
 
-        vp = jnp.concatenate([valid_local, jnp.zeros((bpad,), bool)]) \
-            if bpad else valid_local
-        xs = {
-            "keys": ota.block_view(
-                ota.pad_agent_axis(agent_keys, bpad), nb, blk),
-            "stacks": ota.block_view(
-                ota.pad_agent_axis(lane_stacks, bpad), nb, blk),
-            "valid": vp.reshape(nb, blk),
-        }
-        if ota_cfg is not None:
-            hp = jnp.concatenate([h, jnp.zeros((bpad,), h.dtype)]) \
-                if bpad else h
-            xs["gains"] = hp.reshape(nb, blk)
+        with jax.named_scope(ROLLOUT):
+            vp = jnp.concatenate([valid_local, jnp.zeros((bpad,), bool)]) \
+                if bpad else valid_local
+            xs = {
+                "keys": ota.block_view(
+                    ota.pad_agent_axis(agent_keys, bpad), nb, blk),
+                "stacks": ota.block_view(
+                    ota.pad_agent_axis(lane_stacks, bpad), nb, blk),
+                "valid": vp.reshape(nb, blk),
+            }
+        if noisy:
+            with jax.named_scope(UPLINK):
+                hp = jnp.concatenate([h, jnp.zeros((bpad,), h.dtype)]) \
+                    if bpad else h
+                xs["gains"] = hp.reshape(nb, blk)
 
         def block_body(carry, x):
             grads_b, trajs_b = jax.vmap(agent_grad)(x["keys"], x["stacks"])
-            gsum = ota.stream_fold_block(carry[0], grads_b, None, x["valid"])
-            ys = {"returns": discounted_return(trajs_b.losses, cfg.gamma)}
-            if want_norms:
-                ys["norms_sq"] = sum(
-                    _probes._leaf_norms(g, blk)
-                    for g in jax.tree.leaves(grads_b))
-            if ota_cfg is None:
+            with _mean_scope(noisy):
+                gsum = ota.stream_fold_block(carry[0], grads_b, None,
+                                             x["valid"])
+            with jax.named_scope(EPILOGUE):
+                ys = {"returns": discounted_return(trajs_b.losses,
+                                                   cfg.gamma)}
+                if want_norms:
+                    ys["norms_sq"] = sum(
+                        _probes._leaf_norms(g, blk)
+                        for g in jax.tree.leaves(grads_b))
+            if not noisy:
                 return (gsum,), ys
-            v = ota.stream_fold_block(carry[1], grads_b, x["gains"],
-                                      x["valid"])
+            with jax.named_scope(UPLINK):
+                v = ota.stream_fold_block(carry[1], grads_b, x["gains"],
+                                          x["valid"])
             return (gsum, v), ys
 
-        carry0 = (jax.tree.map(jnp.zeros_like, theta),)
-        if ota_cfg is not None:
-            carry0 += (jax.tree.map(jnp.zeros_like, theta),)
+        with _mean_scope(noisy):
+            carry0 = (jax.tree.map(jnp.zeros_like, theta),)
+        if noisy:
+            with jax.named_scope(UPLINK):
+                carry0 += (jax.tree.map(jnp.zeros_like, theta),)
         carry, ys = jax.lax.scan(block_body, carry0, xs)
 
-        mean_grad = jax.tree.map(
-            lambda s: jax.lax.psum(s, axis_name) / cfg.n_agents, carry[0])
+        with _mean_scope(noisy):
+            mean_grad = jax.tree.map(
+                lambda s: jax.lax.psum(s, axis_name) / cfg.n_agents, carry[0])
         v_global = None
-        if ota_cfg is None:
-            update = mean_grad
-            gain_mean = jnp.ones(())
-        else:
-            v_global = jax.tree.map(
-                lambda s: jax.lax.psum(s, axis_name), carry[1])
-            update = ota.stream_finalize(ota_cfg, key_n, v_global,
-                                         cfg.n_agents)
-            gain_mean = jax.lax.psum(jnp.sum(h), axis_name) / cfg.n_agents
-        theta_next = jax.tree.map(
-            lambda p, u: p - cfg.alpha * u, theta, update)
+        with jax.named_scope(UPLINK):
+            if not noisy:
+                update = mean_grad
+            else:
+                v_global = jax.tree.map(
+                    lambda s: jax.lax.psum(s, axis_name), carry[1])
+                update = ota.stream_finalize(ota_cfg, key_n, v_global,
+                                             cfg.n_agents)
+            theta_next = jax.tree.map(
+                lambda p, u: p - cfg.alpha * u, theta, update)
 
         # metrics: restore absolute local order, mask phantoms, psum
-        returns = ys["returns"].reshape(
-            (nb * blk,) + ys["returns"].shape[2:])[:n_local]
-        r_local = -jnp.sum(jnp.where(valid_local[:, None], returns, 0.0))
-        reward = jax.lax.psum(r_local, axis_name) / (cfg.n_agents * cfg.batch_m)
-        grad_sq = tree_global_norm_sq(mean_grad)
-        if telemetry is None:
-            return theta_next, (reward, grad_sq, gain_mean)
+        with jax.named_scope(EPILOGUE):
+            gain_mean = jax.lax.psum(jnp.sum(h), axis_name) / cfg.n_agents \
+                if noisy else jnp.ones(())
+            returns = ys["returns"].reshape(
+                (nb * blk,) + ys["returns"].shape[2:])[:n_local]
+            r_local = -jnp.sum(jnp.where(valid_local[:, None], returns, 0.0))
+            reward = jax.lax.psum(r_local, axis_name) \
+                / (cfg.n_agents * cfg.batch_m)
+            grad_sq = tree_global_norm_sq(mean_grad)
+            if telemetry is None:
+                return theta_next, (reward, grad_sq, gain_mean)
 
-        norms_sq = ys["norms_sq"].reshape(-1)[:n_local] if want_norms \
-            else None
-        probes = _probes.sharded_streamed_round_probes(
-            telemetry, v=v_global, local_norms_sq=norms_sq,
-            valid_local=valid_local, ota_cfg=ota_cfg, n_agents=cfg.n_agents,
-            axis_name=axis_name,
-            param_dim=sum(int(p.size) for p in jax.tree.leaves(theta)),
-            gain_mean=gain_mean,
-            update_norm=jnp.sqrt(tree_global_norm_sq(update)))
-        return theta_next, (reward, grad_sq, gain_mean, probes)
+            norms_sq = ys["norms_sq"].reshape(-1)[:n_local] if want_norms \
+                else None
+            probes = _probes.sharded_streamed_round_probes(
+                telemetry, v=v_global, local_norms_sq=norms_sq,
+                valid_local=valid_local, ota_cfg=ota_cfg,
+                n_agents=cfg.n_agents, axis_name=axis_name,
+                param_dim=sum(int(p.size) for p in jax.tree.leaves(theta)),
+                gain_mean=gain_mean,
+                update_norm=jnp.sqrt(tree_global_norm_sq(update)))
+            return theta_next, (reward, grad_sq, gain_mean, probes)
 
     def local_round_streamed_svc(theta, agent_keys, lane_stacks, key_chan,
                                  round_idx, part_key, sched_key):
@@ -796,109 +864,125 @@ def _make_agent_sharded_round_fn(
             traj = rollout_batch(e, policy, theta, k, cfg.horizon, cfg.batch_m)
             return grad_fn(policy, theta, traj, cfg.gamma), traj
 
-        _, valid_local = ota._sharded_stream_meta(
-            (axis_name,), n_local, cfg.n_agents)
-        idx, _ = ota._flat_axis_index((axis_name,))
-        gids = idx * n_local + jnp.arange(n_local, dtype=jnp.int32)
-        mask_local = jnp.logical_and(
-            svc_participation.round_mask(part, part_key, sched_key,
-                                         round_idx, gids, cfg.n_agents),
-            valid_local)
-        mf_local = mask_local.astype(jnp.float32)
-        count_p = jax.lax.psum(jnp.sum(mf_local), axis_name)
-        w_norm = count_p if part.debias == "realized" else jnp.asarray(
-            svc_participation.expected_count(part, cfg.n_agents), jnp.float32)
-        inv_w = svc_participation.safe_inv(w_norm)
+        noisy = ota_cfg is not None
+        with jax.named_scope(ROLLOUT):
+            _, valid_local = ota._sharded_stream_meta(
+                (axis_name,), n_local, cfg.n_agents)
+        with jax.named_scope(UPLINK):
+            idx, _ = ota._flat_axis_index((axis_name,))
+            gids = idx * n_local + jnp.arange(n_local, dtype=jnp.int32)
+            mask_local = jnp.logical_and(
+                svc_participation.round_mask(part, part_key, sched_key,
+                                             round_idx, gids, cfg.n_agents),
+                valid_local)
+            mf_local = mask_local.astype(jnp.float32)
+            count_p = jax.lax.psum(jnp.sum(mf_local), axis_name)
+            w_norm = count_p if part.debias == "realized" else jnp.asarray(
+                svc_participation.expected_count(part, cfg.n_agents),
+                jnp.float32)
+            inv_w = svc_participation.safe_inv(w_norm)
 
-        if ota_cfg is not None:
-            key_h, key_n = jax.random.split(key_chan)
-            h, valid_local = ota.sharded_stream_gains(
-                ota_cfg, key_h, (axis_name,), n_local, cfg.n_agents)
-            hm = jnp.where(mask_local, h, jnp.zeros_like(h))
+            if noisy:
+                key_h, key_n = jax.random.split(key_chan)
+                h, valid_local = ota.sharded_stream_gains(
+                    ota_cfg, key_h, (axis_name,), n_local, cfg.n_agents)
+                hm = jnp.where(mask_local, h, jnp.zeros_like(h))
 
         def _pad_row(a):
             return jnp.concatenate(
                 [a, jnp.zeros((bpad,), a.dtype)]) if bpad else a
 
-        vp = _pad_row(valid_local)
-        xs = {
-            "keys": ota.block_view(
-                ota.pad_agent_axis(agent_keys, bpad), nb, blk),
-            "stacks": ota.block_view(
-                ota.pad_agent_axis(lane_stacks, bpad), nb, blk),
-            "valid": vp.reshape(nb, blk),
-            "pmask": _pad_row(mf_local).reshape(nb, blk),
-        }
-        if ota_cfg is not None:
-            xs["gains"] = _pad_row(hm).reshape(nb, blk)
+        with jax.named_scope(ROLLOUT):
+            vp = _pad_row(valid_local)
+            xs = {
+                "keys": ota.block_view(
+                    ota.pad_agent_axis(agent_keys, bpad), nb, blk),
+                "stacks": ota.block_view(
+                    ota.pad_agent_axis(lane_stacks, bpad), nb, blk),
+                "valid": vp.reshape(nb, blk),
+            }
+        with jax.named_scope(UPLINK):
+            xs["pmask"] = _pad_row(mf_local).reshape(nb, blk)
+            if noisy:
+                xs["gains"] = _pad_row(hm).reshape(nb, blk)
 
         def block_body(carry, x):
             grads_b, trajs_b = jax.vmap(agent_grad)(x["keys"], x["stacks"])
-            gsum = ota.stream_fold_block(carry[0], grads_b, x["pmask"],
-                                         x["valid"])
-            ys = {"returns": discounted_return(trajs_b.losses, cfg.gamma)}
-            if want_norms:
-                ys["norms_sq"] = sum(
-                    _probes._leaf_norms(g, blk)
-                    for g in jax.tree.leaves(grads_b))
-            if ota_cfg is None:
+            with _mean_scope(noisy):
+                gsum = ota.stream_fold_block(carry[0], grads_b, x["pmask"],
+                                             x["valid"])
+            with jax.named_scope(EPILOGUE):
+                ys = {"returns": discounted_return(trajs_b.losses,
+                                                   cfg.gamma)}
+                if want_norms:
+                    ys["norms_sq"] = sum(
+                        _probes._leaf_norms(g, blk)
+                        for g in jax.tree.leaves(grads_b))
+            if not noisy:
                 return (gsum,), ys
-            v = ota.stream_fold_block(carry[1], grads_b, x["gains"],
-                                      x["valid"])
+            with jax.named_scope(UPLINK):
+                v = ota.stream_fold_block(carry[1], grads_b, x["gains"],
+                                          x["valid"])
             return (gsum, v), ys
 
-        carry0 = (jax.tree.map(jnp.zeros_like, theta),)
-        if ota_cfg is not None:
-            carry0 += (jax.tree.map(jnp.zeros_like, theta),)
+        with _mean_scope(noisy):
+            carry0 = (jax.tree.map(jnp.zeros_like, theta),)
+        if noisy:
+            with jax.named_scope(UPLINK):
+                carry0 += (jax.tree.map(jnp.zeros_like, theta),)
         carry, ys = jax.lax.scan(block_body, carry0, xs)
 
-        mean_grad = jax.tree.map(
-            lambda s: jax.lax.psum(s, axis_name) * inv_w, carry[0])
+        with _mean_scope(noisy):
+            mean_grad = jax.tree.map(
+                lambda s: jax.lax.psum(s, axis_name) * inv_w, carry[0])
         v_global = None
-        if ota_cfg is None:
-            update = mean_grad
-            gain_mean = jnp.ones(())
-        else:
-            v_global = jax.tree.map(
-                lambda s: jax.lax.psum(s, axis_name), carry[1])
-            update = ota.stream_finalize(ota_cfg, key_n, v_global,
-                                         cfg.n_agents, n_eff=w_norm)
+        with jax.named_scope(UPLINK):
+            if not noisy:
+                update = mean_grad
+            else:
+                v_global = jax.tree.map(
+                    lambda s: jax.lax.psum(s, axis_name), carry[1])
+                update = ota.stream_finalize(ota_cfg, key_n, v_global,
+                                             cfg.n_agents, n_eff=w_norm)
+            theta_next = jax.tree.map(
+                lambda p, u: p - cfg.alpha * u, theta, update)
+
+        with jax.named_scope(EPILOGUE):
             gain_mean = jax.lax.psum(jnp.sum(hm), axis_name) \
-                * svc_participation.safe_inv(count_p)
-        theta_next = jax.tree.map(
-            lambda p, u: p - cfg.alpha * u, theta, update)
+                * svc_participation.safe_inv(count_p) if noisy \
+                else jnp.ones(())
+            returns = ys["returns"].reshape(
+                (nb * blk,) + ys["returns"].shape[2:])[:n_local]
+            r_local = -jnp.sum(jnp.where(mask_local[:, None], returns, 0.0))
+            reward = jax.lax.psum(r_local, axis_name) \
+                * svc_participation.safe_inv(count_p) / cfg.batch_m
+            grad_sq = tree_global_norm_sq(mean_grad)
+            if telemetry is None:
+                return theta_next, (reward, grad_sq, gain_mean)
 
-        returns = ys["returns"].reshape(
-            (nb * blk,) + ys["returns"].shape[2:])[:n_local]
-        r_local = -jnp.sum(jnp.where(mask_local[:, None], returns, 0.0))
-        reward = jax.lax.psum(r_local, axis_name) \
-            * svc_participation.safe_inv(count_p) / cfg.batch_m
-        grad_sq = tree_global_norm_sq(mean_grad)
-        if telemetry is None:
-            return theta_next, (reward, grad_sq, gain_mean)
-
-        norms_sq = ys["norms_sq"].reshape(-1)[:n_local] if want_norms \
-            else None
-        probes = _probes.sharded_streamed_round_probes(
-            telemetry, v=v_global, local_norms_sq=norms_sq,
-            valid_local=mask_local, ota_cfg=ota_cfg, n_agents=cfg.n_agents,
-            axis_name=axis_name,
-            param_dim=sum(int(p.size) for p in jax.tree.leaves(theta)),
-            gain_mean=gain_mean,
-            update_norm=jnp.sqrt(tree_global_norm_sq(update)))
-        probes = _probes.participation_probes(
-            telemetry, probes, rate_realized=count_p / cfg.n_agents,
-            rate_expected=svc_participation.expected_count(
-                part, cfg.n_agents) / cfg.n_agents)
-        return theta_next, (reward, grad_sq, gain_mean, probes)
+            norms_sq = ys["norms_sq"].reshape(-1)[:n_local] if want_norms \
+                else None
+            probes = _probes.sharded_streamed_round_probes(
+                telemetry, v=v_global, local_norms_sq=norms_sq,
+                valid_local=mask_local, ota_cfg=ota_cfg,
+                n_agents=cfg.n_agents, axis_name=axis_name,
+                param_dim=sum(int(p.size) for p in jax.tree.leaves(theta)),
+                gain_mean=gain_mean,
+                update_norm=jnp.sqrt(tree_global_norm_sq(update)))
+            probes = _probes.participation_probes(
+                telemetry, probes, rate_realized=count_p / cfg.n_agents,
+                rate_expected=svc_participation.expected_count(
+                    part, cfg.n_agents) / cfg.n_agents)
+            return theta_next, (reward, grad_sq, gain_mean, probes)
 
     def round_fn(theta: PyTree, key: jax.Array):
-        key_samp, key_chan = jax.random.split(key)
-        agent_keys = jax.random.split(key_samp, cfg.n_agents)
-        lane_stacks = dict(env.params) if hetero else {}
-        if agent_blocks is not None and pad_total:
-            agent_keys = ota.pad_agent_axis(agent_keys, pad_total)
-            lane_stacks = ota.pad_agent_axis(lane_stacks, pad_total)
+        with jax.named_scope(ROLLOUT):
+            key_samp, key_chan = jax.random.split(key)
+            agent_keys = jax.random.split(key_samp, cfg.n_agents)
+            lane_stacks = dict(env.params) if hetero else {}
+            if agent_blocks is not None and pad_total:
+                agent_keys = ota.pad_agent_axis(agent_keys, pad_total)
+                lane_stacks = ota.pad_agent_axis(lane_stacks, pad_total)
         stack_specs = jax.tree.map(lambda _: P(axis_name), lane_stacks)
         metric_specs = (P(), P(), P())
         if telemetry is not None:
@@ -917,12 +1001,13 @@ def _make_agent_sharded_round_fn(
 
     def service_round(state: ServiceState, key: jax.Array):
         theta = state.theta
-        key_samp, key_chan = jax.random.split(key)
-        agent_keys = jax.random.split(key_samp, cfg.n_agents)
-        lane_stacks = dict(env.params) if hetero else {}
-        if pad_total:
-            agent_keys = ota.pad_agent_axis(agent_keys, pad_total)
-            lane_stacks = ota.pad_agent_axis(lane_stacks, pad_total)
+        with jax.named_scope(ROLLOUT):
+            key_samp, key_chan = jax.random.split(key)
+            agent_keys = jax.random.split(key_samp, cfg.n_agents)
+            lane_stacks = dict(env.params) if hetero else {}
+            if pad_total:
+                agent_keys = ota.pad_agent_axis(agent_keys, pad_total)
+                lane_stacks = ota.pad_agent_axis(lane_stacks, pad_total)
         stack_specs = jax.tree.map(lambda _: P(axis_name), lane_stacks)
         metric_specs = (P(), P(), P())
         if telemetry is not None:
@@ -935,11 +1020,23 @@ def _make_agent_sharded_round_fn(
             check_vma=False,
         )(theta, agent_keys, lane_stacks, key_chan, state.round_idx,
           state.part_key, state.sched_key)
-        state_next = state._replace(theta=theta_next,
-                                    round_idx=state.round_idx + 1)
+        with jax.named_scope(UPLINK):
+            state_next = state._replace(theta=theta_next,
+                                        round_idx=state.round_idx + 1)
         return state_next, metrics
 
     return service_round
+
+
+def agents_rolled_out(n_agents: int, agent_blocks: Optional[int] = None
+                      ) -> int:
+    """Agents a one-chip round passes through ``rollout_batch``, from its
+    layout: with ``agent_blocks`` the blocked scan rolls out its phantom
+    tail too."""
+    if agent_blocks is None:
+        return n_agents
+    n_blocks, block, _ = ota.blocked_layout(n_agents, agent_blocks)
+    return n_blocks * block
 
 
 def run(
@@ -1099,15 +1196,17 @@ def run_jit(env, policy, cfg: FedPGConfig, key, *, ota=None, theta0=None,
     if theta0 is None and _hashable(env, policy, cfg, ota, telemetry,
                                     agent_mesh, agent_axis, agent_blocks,
                                     participation, staleness):
-        return _compiled_run(env, policy, cfg, ota, ota_backend, telemetry,
-                             agent_mesh, agent_axis, agent_blocks,
-                             participation, staleness)(key)
-    fn = jax.jit(lambda k: run(env, policy, cfg, k, ota=ota, theta0=theta0,
-                               ota_backend=ota_backend, telemetry=telemetry,
-                               agent_mesh=agent_mesh, agent_axis=agent_axis,
-                               agent_blocks=agent_blocks,
-                               participation=participation,
-                               staleness=staleness))
+        fn = _compiled_run(env, policy, cfg, ota, ota_backend, telemetry,
+                           agent_mesh, agent_axis, agent_blocks,
+                           participation, staleness)
+    else:
+        fn = jax.jit(lambda k: run(
+            env, policy, cfg, k, ota=ota, theta0=theta0,
+            ota_backend=ota_backend, telemetry=telemetry,
+            agent_mesh=agent_mesh, agent_axis=agent_axis,
+            agent_blocks=agent_blocks, participation=participation,
+            staleness=staleness))
+    scopes.record(fn, key)
     return fn(key)
 
 
@@ -1139,15 +1238,17 @@ def monte_carlo(
     keys = jax.random.split(key, n_runs)
     if _hashable(env, policy, cfg, ota, telemetry, agent_mesh, agent_axis,
                  agent_blocks, participation, staleness):
-        return _compiled_monte_carlo(env, policy, cfg, ota, n_runs,
-                                     ota_backend, telemetry, agent_mesh,
-                                     agent_axis, agent_blocks,
-                                     participation, staleness)(keys)
-    fn = jax.jit(jax.vmap(
-        lambda k: run(env, policy, cfg, k, ota=ota,
-                      ota_backend=ota_backend, telemetry=telemetry,
-                      agent_mesh=agent_mesh, agent_axis=agent_axis,
-                      agent_blocks=agent_blocks,
-                      participation=participation,
-                      staleness=staleness)[1]))
+        fn = _compiled_monte_carlo(env, policy, cfg, ota, n_runs,
+                                   ota_backend, telemetry, agent_mesh,
+                                   agent_axis, agent_blocks,
+                                   participation, staleness)
+    else:
+        fn = jax.jit(jax.vmap(
+            lambda k: run(env, policy, cfg, k, ota=ota,
+                          ota_backend=ota_backend, telemetry=telemetry,
+                          agent_mesh=agent_mesh, agent_axis=agent_axis,
+                          agent_blocks=agent_blocks,
+                          participation=participation,
+                          staleness=staleness)[1]))
+    scopes.record(fn, keys)
     return fn(keys)

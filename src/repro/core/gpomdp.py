@@ -19,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.rl.sampler import Trajectory
+from repro.telemetry.scopes import ESTIMATOR
 
 PyTree = Any
 
@@ -93,16 +94,19 @@ def gpomdp_gradient(
     policy, params: PyTree, traj: Trajectory, gamma: float,
     weights: jax.Array | None = None,
 ) -> PyTree:
-    """The estimator itself: grad_theta of the G(PO)MDP surrogate."""
-    return jax.grad(
-        lambda p: gpomdp_surrogate(policy, p, traj, gamma, weights)
-    )(params)
+    """The estimator itself: grad_theta of the G(PO)MDP surrogate, forward
+    and backward under the ``estimator`` named scope."""
+    with jax.named_scope(ESTIMATOR):
+        return jax.grad(
+            lambda p: gpomdp_surrogate(policy, p, traj, gamma, weights)
+        )(params)
 
 
 def reinforce_gradient(
     policy, params: PyTree, traj: Trajectory, gamma: float,
     weights: jax.Array | None = None,
 ) -> PyTree:
-    return jax.grad(
-        lambda p: reinforce_surrogate(policy, p, traj, gamma, weights)
-    )(params)
+    with jax.named_scope(ESTIMATOR):
+        return jax.grad(
+            lambda p: reinforce_surrogate(policy, p, traj, gamma, weights)
+        )(params)

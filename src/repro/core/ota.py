@@ -60,6 +60,7 @@ import jax.numpy as jnp
 
 from repro.core.channel import Channel, IdealChannel
 from repro.core.power_control import PowerPolicy, effective_moments
+from repro.telemetry.scopes import UPLINK
 from repro.utils.tree import tree_normal_like
 
 PyTree = Any
@@ -321,20 +322,22 @@ def aggregate_apply(
     update).  ``agent_blocks`` streams the agent axis in blocked-scan
     chunks (see :func:`aggregate`); on pallas the final noise + debias +
     SGD tail then still runs as one fused kernel pass over the accumulated
-    superposition.  Returns ``(theta', h)``.
+    superposition.  Returns ``(theta', h)``; runs under the ``uplink``
+    named scope.
     """
     sp = _make_spec(cfg, None, False, backend)
-    if agent_blocks is not None and not sp.exact \
-            and sp.resolved_backend() == "pallas":
-        return _aggregate_apply_streamed_pallas(
-            cfg, key, grads, params, alpha, agent_blocks, gains=gains)
-    if sp.exact or sp.resolved_backend() == "xla":
-        u, h = aggregate(grads, cfg, key=key, gains=gains,
-                         agent_blocks=agent_blocks,
-                         spec=replace(sp, backend="xla"))
-        return jax.tree.map(lambda p, x: p - alpha * x, params, u), h
-    return _aggregate_apply_pallas(cfg, key, grads, params, alpha,
-                                   gains=gains)
+    with jax.named_scope(UPLINK):
+        if agent_blocks is not None and not sp.exact \
+                and sp.resolved_backend() == "pallas":
+            return _aggregate_apply_streamed_pallas(
+                cfg, key, grads, params, alpha, agent_blocks, gains=gains)
+        if sp.exact or sp.resolved_backend() == "xla":
+            u, h = aggregate(grads, cfg, key=key, gains=gains,
+                             agent_blocks=agent_blocks,
+                             spec=replace(sp, backend="xla"))
+            return jax.tree.map(lambda p, x: p - alpha * x, params, u), h
+        return _aggregate_apply_pallas(cfg, key, grads, params, alpha,
+                                       gains=gains)
 
 
 def uplink_jaxpr(cfg: Optional[OTAConfig], *, n_agents: int = 4,
@@ -777,20 +780,22 @@ def stream_finalize(
     flattened ``v`` with the counter PRNG (noise indexed by absolute flat
     position, so it too is invariant to the agent blocking).  ``n_eff``
     retargets the normaliser at the round service's effective
-    contribution weight (see :func:`_participation_rescale`)."""
-    if backend == "pallas":
-        from repro.kernels import ota_fused
+    contribution weight (see :func:`_participation_rescale`).  Runs under
+    the ``uplink`` named scope."""
+    with jax.named_scope(UPLINK):
+        if backend == "pallas":
+            from repro.kernels import ota_fused
 
-        flat, unflatten = _flatten_params(v)
-        u = ota_fused.fused_server_pass(
-            flat,
-            sigma=cfg.noise_sigma,
-            scale=_server_scale(cfg, n_agents, n_agents, n_eff),
-            seed=_kernel_seed(key_n),
-            with_noise=_noise_enabled(cfg.noise_sigma),
-        )
-        return unflatten(u)
-    return _server_epilogue(cfg, key_n, v, n_agents, n_agents, n_eff)
+            flat, unflatten = _flatten_params(v)
+            u = ota_fused.fused_server_pass(
+                flat,
+                sigma=cfg.noise_sigma,
+                scale=_server_scale(cfg, n_agents, n_agents, n_eff),
+                seed=_kernel_seed(key_n),
+                with_noise=_noise_enabled(cfg.noise_sigma),
+            )
+            return unflatten(u)
+        return _server_epilogue(cfg, key_n, v, n_agents, n_agents, n_eff)
 
 
 def stream_finalize_apply(
@@ -805,24 +810,26 @@ def stream_finalize_apply(
     n_eff: Optional[Scalar] = None,
 ) -> PyTree:
     """`stream_finalize` fused with the server SGD step
-    ``theta' = theta - alpha * u`` (one kernel pass on pallas)."""
-    if backend == "pallas":
-        from repro.kernels import ota_fused
+    ``theta' = theta - alpha * u`` (one kernel pass on pallas), under the
+    ``uplink`` named scope."""
+    with jax.named_scope(UPLINK):
+        if backend == "pallas":
+            from repro.kernels import ota_fused
 
-        flat, _ = _flatten_params(v)
-        pflat, punflatten = _flatten_params(params)
-        p_next = ota_fused.fused_server_pass(
-            flat,
-            sigma=cfg.noise_sigma,
-            scale=_server_scale(cfg, n_agents, n_agents, n_eff),
-            seed=_kernel_seed(key_n),
-            with_noise=_noise_enabled(cfg.noise_sigma),
-            alpha=alpha,
-            params=pflat,
-        )
-        return punflatten(p_next)
-    u = _server_epilogue(cfg, key_n, v, n_agents, n_agents, n_eff)
-    return jax.tree.map(lambda p, x: p - alpha * x, params, u)
+            flat, _ = _flatten_params(v)
+            pflat, punflatten = _flatten_params(params)
+            p_next = ota_fused.fused_server_pass(
+                flat,
+                sigma=cfg.noise_sigma,
+                scale=_server_scale(cfg, n_agents, n_agents, n_eff),
+                seed=_kernel_seed(key_n),
+                with_noise=_noise_enabled(cfg.noise_sigma),
+                alpha=alpha,
+                params=pflat,
+            )
+            return punflatten(p_next)
+        u = _server_epilogue(cfg, key_n, v, n_agents, n_agents, n_eff)
+        return jax.tree.map(lambda p, x: p - alpha * x, params, u)
 
 
 def _aggregate_stacked_streamed(

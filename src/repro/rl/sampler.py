@@ -12,6 +12,8 @@ from typing import Any, NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.telemetry.scopes import ROLLOUT
+
 PyTree = Any
 
 
@@ -47,9 +49,12 @@ def rollout(env, policy, params: PyTree, key: jax.Array, horizon: int) -> Trajec
 def rollout_batch(
     env, policy, params: PyTree, key: jax.Array, horizon: int, batch: int
 ) -> Trajectory:
-    """(batch,) independent trajectories; arrays gain a leading batch dim."""
-    keys = jax.random.split(key, batch)
-    return jax.vmap(lambda k: rollout(env, policy, params, k, horizon))(keys)
+    """(batch,) independent trajectories; arrays gain a leading batch dim.
+    Runs under the ``rollout`` named scope."""
+    with jax.named_scope(ROLLOUT):
+        keys = jax.random.split(key, batch)
+        return jax.vmap(lambda k: rollout(env, policy, params, k, horizon))(
+            keys)
 
 
 def discounted_return(losses: jax.Array, gamma: float) -> jax.Array:

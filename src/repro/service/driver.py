@@ -10,6 +10,16 @@ between commits, and each commit emits a ledger event with the round
 service's telemetry (realised participation rate, realised-vs-expected
 debias drift, staleness age histogram) plus a ``trace`` span.
 
+The commit span, ``service_commit``, holds three children —
+``service.dispatch`` (enqueue the segment), ``service.fetch`` (the
+``device_get``, where the host waits on the chip) and ``service.record``
+(summary, ledger, checkpoint).  All four carry ``round``, the commit's
+first round index.  The commit span also counts ``agents_rolled_out``
+(agents the segment passed through ``rollout_batch``, phantom block
+padding included), ``agents_participating`` (the participation probe's
+realised count, when that probe is on) and ``compiles`` (backend compiles
+during the commit).
+
 Determinism and resume: per-round scan keys are derived by
 ``fold_in(round_key, absolute_round_index)`` — NOT by splitting a
 carried key — so round k consumes the identical key stream whether it
@@ -31,12 +41,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.analyze.budget import backend_compiles
 from repro.core import fedpg
 from repro.service import participation as svc_participation
 from repro.service import staleness as svc_staleness
 from repro.service.participation import ParticipationConfig, ServiceState
 from repro.service.staleness import StalenessConfig, StaleState
-from repro.telemetry import get_ledger, trace
+from repro.telemetry import get_ledger, scopes, trace
 from repro.telemetry.probes import TelemetryConfig, summarize
 
 PyTree = Any
@@ -125,6 +136,9 @@ class RoundService:
 
         self._segment = jax.jit(_segment)
         self._commits = 0
+        self._rolled_out = seg * fedpg.agents_rolled_out(cfg.n_agents,
+                                                         agent_blocks)
+        backend_compiles()  # the compile listener, registered once
 
     # -- checkpointing -----------------------------------------------------
 
@@ -184,11 +198,26 @@ class RoundService:
         also written to the ambient ledger (if one is installed)."""
         svc = self.service
         r0 = int(self.state.round_idx)
-        with trace.span("service_commit", round_start=r0,
+        compiles0 = backend_compiles()
+        with trace.span("service_commit", round=r0,
                         rounds=svc.rounds_per_commit) as sp:
-            state, metrics = self._segment(
-                self.state, self._round_key, jnp.int32(r0))
-            metrics = jax.tree.map(np.asarray, jax.device_get(metrics))
+            with trace.span("service.dispatch", round=r0) as dispatch:
+                args = (self.state, self._round_key, jnp.int32(r0))
+                scopes.record(self._segment, *args)
+                state, metrics = self._segment(*args)
+            with trace.span("service.fetch", round=r0) as fetch:
+                trace.wait(metrics)
+                metrics = jax.tree.map(np.asarray, jax.device_get(metrics))
+            sp.attrs.update(agents_rolled_out=self._rolled_out,
+                            compiles=backend_compiles() - compiles0)
+            with trace.span("service.record", round=r0):
+                rec = self._record(r0, state, metrics, sp,
+                                   dispatch.duration_us + fetch.duration_us)
+        return rec
+
+    def _record(self, r0: int, state: ServiceState, metrics,
+                sp: trace.Span, wall_us: float) -> Dict[str, Any]:
+        svc = self.service
         self.state = state
         self._commits += 1
 
@@ -199,9 +228,13 @@ class RoundService:
             "reward": float(np.mean(rewards)),
             "grad_sq": float(np.mean(grad_sq)),
             "gain_mean": float(np.mean(gain_mean)),
-            "wall_us": sp.duration_us,
+            "wall_us": wall_us,
         }
         if len(metrics) == 4:
+            rate = metrics[3].participation_rate
+            if rate is not None and not np.isnan(rate).any():
+                sp.attrs["agents_participating"] = int(round(
+                    float(np.sum(rate, dtype=np.float64)) * self.cfg.n_agents))
             tel = summarize(metrics[3])
             if tel is not None:
                 rec.update({k: v for k, v in tel.items() if k in (
@@ -216,7 +249,7 @@ class RoundService:
                 np.clip(age, 0, self._stale.max_age + 1),
                 minlength=self._stale.max_age + 2)
             rec["staleness_hist"] = [int(c) for c in hist]
-        per_round_s = sp.duration_us / 1e6 / svc.rounds_per_commit
+        per_round_s = wall_us / 1e6 / svc.rounds_per_commit
         if svc.round_deadline_s is not None \
                 and per_round_s > svc.round_deadline_s:
             rec["deadline_exceeded"] = True

@@ -12,15 +12,18 @@ Three layers, designed to compose:
 * :mod:`repro.telemetry.trace` — the span tracer (``with trace.span(...)``)
   that owns all wall-clock timing; exports Chrome trace-event JSON
   (Perfetto-loadable) of sweep partition compile/dispatch/materialize and
-  benchmark phases.
+  benchmark phases, and writes each span into a running profiler trace.
+* :mod:`repro.telemetry.scopes` — the round's four named scopes (rollout,
+  estimator, uplink, epilogue) and the map from the compiled device ops
+  of the last recorded call to them.
 * :mod:`repro.telemetry.ledger` — a JSONL event log per run (platform,
   compile counts, per-scenario results vs the Theorem-1/2 floors,
   telemetry summaries) rendered to markdown by
   ``python -m repro.telemetry.report``.
 
-The ``trace`` and ``ledger`` modules are themselves jax-free (import them
-directly in jax-less tooling); only ``probes`` — and this package init,
-which re-exports it — pulls in jax.
+The ``trace``, ``scopes`` and ``ledger`` modules are themselves jax-free
+(import them directly in jax-less tooling); only ``probes`` — and this
+package init, which re-exports it — pulls in jax.
 """
 from repro.telemetry.ledger import (  # noqa: F401
     Ledger, get_ledger, read_ledger, set_ledger, using_ledger,

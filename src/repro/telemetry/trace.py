@@ -20,31 +20,43 @@ This module is the *owner* of raw-clock access: the ``raw-timing`` analyze
 rule flags ``time.perf_counter()`` call sites anywhere outside
 ``src/repro/telemetry/``, so new timing code must come through here.
 
-``jax_profile(logdir)`` optionally bridges a block to ``jax.profiler.trace``
-for XLA-level timelines next to the host-side spans; it degrades to a
-no-op when the profiler is unavailable.
+Spans are on the profiler's clock: start times are wall-clock (epoch)
+microseconds, the clock the profiler's host events use, and every span
+also enters a ``jax.profiler.TraceAnnotation`` of the same name with its
+attributes as stats.  Under ``jax.profiler.trace`` (or
+``start_trace``/``stop_trace``) a span therefore lands in the profile's
+host plane beside the device ops it dispatched; with the profiler off the
+annotation is a no-op.  An xplane's event times count from its
+``profile_start_time`` stat (wall-clock ns), so ``start_us * 1e3`` minus
+that stat is the span's place in the profile.  A profiler records only
+the annotations entered while it runs: a span that waits on the device
+calls ``trace.wait(arrays)`` inside it, which re-enters the span's
+annotation when a profiler starts mid-wait.  Without jax installed the
+spans stay host-only.
 """
 from __future__ import annotations
 
+import functools
 import json
 import os
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Iterator, List, Optional
 
 __all__ = [
-    "Span", "Timing", "Tracer", "export", "get_tracer", "jax_profile",
-    "reset", "span", "spans", "timed_call", "to_chrome_trace",
+    "Span", "Timing", "Tracer", "export", "get_tracer", "reset", "span",
+    "spans", "timed_call", "to_chrome_trace", "wait",
 ]
 
 
 @dataclass
 class Span:
-    """One timed interval.  ``duration_us`` is valid after the ``with``
-    block exits; ``attrs`` may be extended inside the block (they export
-    as the Chrome event's ``args``)."""
+    """One timed interval.  ``start_us`` is wall-clock (epoch) µs;
+    ``duration_us`` is valid after the ``with`` block exits; ``attrs`` may
+    be extended inside the block (they export as the Chrome event's
+    ``args``; the profiler sees those given at entry)."""
 
     name: str
     start_us: float
@@ -52,6 +64,7 @@ class Span:
     attrs: Dict[str, Any] = field(default_factory=dict)
     children: List["Span"] = field(default_factory=list)
     tid: int = 0
+    profiled: bool = False  # a running profiler recorded its entry
 
 
 class Timing(float):
@@ -73,33 +86,77 @@ class Timing(float):
         return self
 
 
+# how often a device wait checks whether a profiler has started
+_POLL_S = 0.01
+
+
 class Tracer:
     """A span tree with a per-thread open-span stack."""
 
     def __init__(self) -> None:
-        self._t0 = time.perf_counter()
         self._lock = threading.Lock()
         self._stacks: Dict[int, List[Span]] = {}
         self.roots: List[Span] = []
 
-    def _now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
+    @staticmethod
+    def _now_us() -> float:
+        return time.time_ns() / 1e3
 
     @contextmanager
     def span(self, name: str, **attrs: Any) -> Iterator[Span]:
         tid = threading.get_ident()
-        sp = Span(name=name, start_us=self._now_us(), attrs=dict(attrs),
-                  tid=tid & 0xFFFF)
-        with self._lock:
-            stack = self._stacks.setdefault(tid, [])
-            (stack[-1].children if stack else self.roots).append(sp)
-            stack.append(sp)
-        try:
-            yield sp
-        finally:
-            sp.duration_us = self._now_us() - sp.start_us
+        with _annotation(name, attrs):
+            sp = Span(name=name, start_us=self._now_us(), attrs=dict(attrs),
+                      tid=tid & 0xFFFF, profiled=_profiling())
             with self._lock:
-                self._stacks[tid].pop()
+                stack = self._stacks.setdefault(tid, [])
+                (stack[-1].children if stack else self.roots).append(sp)
+                stack.append(sp)
+            try:
+                yield sp
+            finally:
+                sp.duration_us = self._now_us() - sp.start_us
+                with self._lock:
+                    self._stacks[tid].pop()
+
+    def wait(self, tree: Any) -> None:
+        """Block until every device array in ``tree`` is ready, inside this
+        thread's innermost open span.
+
+        A profiler records only the annotations entered while it runs, so
+        one started during a long wait would miss the span.  Where the
+        span began with no profiler running, a helper thread blocks on the
+        arrays while this one checks every ``_POLL_S`` whether a profiler
+        has started, and then re-enters the span's annotation for the rest
+        of the wait: a profile of the wait's tail still names it.  The wait
+        ends as soon as the arrays are ready."""
+        import jax
+
+        with self._lock:
+            stack = self._stacks.get(threading.get_ident())
+            open_span = stack[-1] if stack else None
+        if open_span is None or open_span.profiled:
+            jax.block_until_ready(tree)
+            return
+        ready, failed = threading.Event(), []
+
+        def block():
+            try:
+                jax.block_until_ready(tree)
+            except BaseException as e:  # re-raised by the caller below
+                failed.append(e)
+            finally:
+                ready.set()
+
+        waiter = threading.Thread(target=block, daemon=True)
+        waiter.start()
+        while not ready.wait(_POLL_S):
+            if _profiling():
+                with _annotation(open_span.name, open_span.attrs):
+                    ready.wait()
+        waiter.join()
+        if failed:
+            raise failed[0]
 
     def reset(self) -> None:
         self.__init__()
@@ -137,6 +194,28 @@ class Tracer:
         return doc
 
 
+@functools.cache
+def _annotation_cls():
+    """``jax.profiler.TraceAnnotation``, imported on first use; None where
+    jax is not installed."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return None
+    return TraceAnnotation
+
+
+def _annotation(name: str, attrs: Dict[str, Any]):
+    """``TraceAnnotation(name, **attrs)``: recorded when a profiler runs."""
+    cls = _annotation_cls()
+    return nullcontext() if cls is None else cls(name, **attrs)
+
+
+def _profiling() -> bool:
+    cls = _annotation_cls()
+    return cls is not None and cls.is_enabled()
+
+
 def _json_safe(v: Any) -> Any:
     """Chrome's ``args`` values must be JSON: numbers/strings/bools pass
     through (non-finite floats stringify), everything else reprs."""
@@ -161,6 +240,13 @@ def get_tracer() -> Tracer:
 def span(name: str, **attrs: Any):
     """``with trace.span("dispatch", partition=i) as sp: ...``"""
     return _TRACER.span(name, **attrs)
+
+
+def wait(tree: Any) -> None:
+    """Block until ``tree``'s device arrays are ready inside the open span,
+    keeping the span visible to a profiler started mid-wait
+    (:meth:`Tracer.wait`)."""
+    _TRACER.wait(tree)
 
 
 def reset() -> None:
@@ -215,18 +301,3 @@ def timed_call(
     run_us = times[len(times) // 2] * 1e6
     sp.attrs["median_us"] = run_us
     return Timing(run_us, compile_us=compile_us)
-
-
-@contextmanager
-def jax_profile(logdir: str) -> Iterator[None]:
-    """Bridge a block to ``jax.profiler.trace(logdir)`` (XLA timeline next
-    to the host spans); silently a no-op when jax or its profiler is
-    unavailable."""
-    try:
-        from jax import profiler
-        ctx = profiler.trace(str(logdir))
-    except Exception:
-        yield
-        return
-    with ctx:
-        yield

@@ -95,6 +95,29 @@ def test_key_reuse_clean_on_guard_return_chain():
     assert fs == []
 
 
+def test_key_reuse_clean_inside_with_block():
+    # a with body is scanned once, like any other block
+    fs = _scan(
+        "import jax\n"
+        "def f(key):\n"
+        "    with jax.named_scope('rollout'):\n"
+        "        k1, k2 = jax.random.split(key)\n"
+        "    return jax.random.normal(k1) + jax.random.uniform(k2)\n",
+        "key-reuse")
+    assert fs == []
+
+
+def test_key_reuse_trips_across_with_block():
+    fs = _scan(
+        "import jax\n"
+        "def f(key):\n"
+        "    with jax.named_scope('rollout'):\n"
+        "        a = jax.random.normal(key)\n"
+        "    return a + jax.random.uniform(key)\n",
+        "key-reuse")
+    assert _ids(fs) == ["key-reuse"] and fs[0].line == 5
+
+
 def test_key_reuse_trips_on_cross_iteration_reuse():
     fs = _scan(
         "import jax\n"

@@ -331,3 +331,384 @@ def test_floor_report():
     assert fr["floor_which"] in ("theorem1", "theorem2")
     assert fr["floor"] in (fr["floor_theorem1"], fr["floor_theorem2"])
     assert fr["floor"] > 0
+
+
+# ---------------------------------------------------------------------------
+# named scopes and the map from device ops to them
+# ---------------------------------------------------------------------------
+
+from repro.analyze.budget import CompileCounter  # noqa: E402
+from repro.service.driver import RoundService, ServiceConfig  # noqa: E402
+from repro.service.participation import ParticipationConfig  # noqa: E402
+from repro.telemetry import scopes  # noqa: E402
+
+_INSTR_META = re.compile(r'^\s*(?:ROOT\s+)?%?([^\s=]+) = .*op_name="([^"]*)"')
+SERVICE_TEL = TelemetryConfig(snr=False, grad_norms=False,
+                              moment_drift=False, dispersion=False,
+                              participation=True)
+
+
+def _named_ops(hlo_text):
+    """(instruction name, op_name) of every instruction whose op_name
+    carries a name stack."""
+    return [m.groups() for m in map(_INSTR_META.match, hlo_text.splitlines())
+            if m is not None and "/" in m.group(2)]
+
+
+def _round_program(kind):
+    env, pol = _setting()
+    cfg = fedpg.FedPGConfig(n_agents=7, batch_m=2, horizon=4, n_rounds=1)
+    kw = {"stacked": {}, "streamed": dict(agent_blocks=3),
+          "service": dict(agent_blocks=3,
+                          participation=ParticipationConfig(rate=0.5))}[kind]
+    round_fn = fedpg.make_round_fn(env, pol, cfg, _rayleigh_ota(),
+                                   telemetry=TelemetryConfig(), **kw)
+    theta = pol.init(jax.random.key(0))
+    if kind == "service":
+        from repro.service import participation as svc_participation
+        theta = svc_participation.init_state(theta, jax.random.key(2),
+                                             cfg.n_agents, None)
+    return jax.jit(round_fn).lower(theta, jax.random.key(1)).compile()
+
+
+# the block loop's and the shard_map's own ops, in no scope: the ``while``
+# and the single ops of its body and condition (slicing the blocks,
+# stacking the outputs, the counter), and the partitioner's own
+_LOOP_OWN = re.compile(
+    r"^jit\([^)]*\)(/(while|body|cond|closed_call|shard_map))*(/[\w.-]+)?$")
+
+
+@pytest.mark.parametrize("kind", ["stacked", "streamed", "service"])
+def test_round_scopes_cover_every_named_op(kind):
+    """Inside a round, every instruction with a name stack falls under one
+    of the four scopes (control flow aside) or is the block loop's own
+    (unscoped), all four occur, and the scatter-add gradient of the
+    ``logp[action]`` gather is the estimator's."""
+    text = _round_program(kind).as_text()
+    ops = scopes.hlo_scopes(text)
+    named = _named_ops(text)
+    assert named
+    stray = [(n, o) for n, o in named
+             if ops[n] not in scopes.SCOPES + (scopes.CONTROL,)]
+    assert all(_LOOP_OWN.match(o) for _, o in stray), [
+        (n, o) for n, o in stray if not _LOOP_OWN.match(o)][:5]
+    assert bool(stray) == (kind != "stacked")
+    assert set(scopes.SCOPES) <= {ops[n] for n, _ in named}
+    scatter = [n for n, o in named if o.endswith("scatter-add")]
+    assert scatter and {ops[n] for n in scatter} <= {
+        scopes.ESTIMATOR, scopes.CONTROL}
+
+
+def test_scope_of_reads_transformed_and_nested_stacks():
+    assert scopes.scope_of("jit(f)/rollout/while/body/vmap(estimator)/"
+                           "transpose(jvp(vmap()))/scatter-add") == "estimator"
+    assert scopes.scope_of("jit(f)/vmap(uplink)/mul") == "uplink"
+    assert scopes.scope_of("jit(f)/while/body/add") == scopes.UNSCOPED
+    assert scopes.scope_of("jit(rollout_x)/add") == scopes.UNSCOPED
+
+
+def test_loop_body_without_metadata_takes_its_loop_scope():
+    """A loop XLA builds (a TPU's expanded scatter) carries no metadata in
+    its body; its ops take the scope of the loop op, and the loop op itself
+    is marked control flow."""
+    text = """HloModule m
+
+%body.1 (p: (s32[], f32[8])) -> (s32[], f32[8]) {
+  %p = (s32[], f32[8]{0}) parameter(0)
+  %dynamic-slice.16 = f32[1]{0} dynamic-slice(%x, %i), dynamic_slice_sizes={1}
+  ROOT %tuple.2 = (s32[], f32[8]{0}) tuple(%i, %y)
+}
+
+%cond.1 (p: (s32[], f32[8])) -> pred[] {
+  ROOT %lt.1 = pred[] compare(%a, %b), direction=LT
+}
+
+ENTRY %main.9 (a: f32[8]) -> f32[8] {
+  %a = f32[8]{0} parameter(0)
+  %while.127 = (s32[], f32[8]{0}) while(%t), condition=%cond.1, body=%body.1, metadata={op_name="jit(f)/estimator/transpose(jvp(g))/scatter-add"}
+  %fusion.3 = f32[8]{0} fusion(%a), kind=kLoop, calls=%fc.3, metadata={op_name="jit(f)/epilogue/mul"}
+  ROOT %add.4 = f32[8]{0} add(%a, %a), metadata={op_name="jit(f)/add"}
+}
+"""
+    ops = scopes.hlo_scopes(text)
+    assert ops["while.127"] == scopes.CONTROL
+    assert ops["dynamic-slice.16"] == ops["lt.1"] == scopes.ESTIMATOR
+    assert ops["fusion.3"] == scopes.EPILOGUE
+    assert ops["add.4"] == ops["a"] == scopes.UNSCOPED
+
+
+def _service(agent_blocks=3):
+    env, pol = _setting()
+    cfg = fedpg.FedPGConfig(n_agents=7, batch_m=2, horizon=4, n_rounds=1)
+    return RoundService(
+        env, pol, cfg, jax.random.key(1),
+        participation=ParticipationConfig(rate=0.5), ota=_rayleigh_ota(),
+        telemetry=SERVICE_TEL, agent_blocks=agent_blocks,
+        service=ServiceConfig(rounds_per_commit=2, max_rounds=100))
+
+
+def _call_entry(entry):
+    env, pol = _setting()
+    cfg = fedpg.FedPGConfig(n_agents=7, batch_m=2, horizon=4, n_rounds=2)
+    if entry == "fedpg.run_jit":
+        fedpg.run_jit(env, pol, cfg, jax.random.key(0), ota=_rayleigh_ota(),
+                      agent_blocks=3)
+    elif entry == "fedpg.monte_carlo":
+        fedpg.monte_carlo(env, pol, cfg, jax.random.key(0), 2,
+                          ota=_rayleigh_ota())
+    else:
+        _service().commit()
+
+
+@pytest.mark.parametrize("entry", ["fedpg.run_jit", "fedpg.monte_carlo",
+                                   "RoundService.commit"])
+def test_entry_point_map_needs_no_compile(entry):
+    """The map of the executable an entry point last ran is built from that
+    executable without a second backend compile, and names all four
+    scopes."""
+    _call_entry(entry)
+    with CompileCounter() as c:
+        ops = scopes.op_scopes()
+    assert c.count == 0
+    assert set(scopes.SCOPES) <= set(ops.values())
+    assert scopes.CONTROL in ops.values()
+
+
+def test_op_scopes_before_any_call_is_none(monkeypatch):
+    monkeypatch.setattr(scopes, "_LAST", None)
+    assert scopes.op_scopes() is None
+
+
+def test_recorded_call_keeps_no_device_arrays():
+    """The record of a commit keeps its arguments' specs, not the arrays:
+    the state a commit replaced is freed, and the map still builds."""
+    import gc
+    import weakref
+
+    svc = _service()
+    svc.commit()
+    old = weakref.ref(jax.tree.leaves(svc.state)[0])
+    svc.commit()
+    gc.collect()
+    assert old() is None
+    _, args = scopes._LAST
+    assert not any(isinstance(a, jax.Array) for a in jax.tree.leaves(args))
+    assert set(scopes.SCOPES) <= set(scopes.op_scopes().values())
+
+
+def test_unscoped_op_in_the_block_loop_reads_unscoped(monkeypatch):
+    """The block loop itself carries no scope, so an op planted in its
+    body outside the four scopes maps to ``unscoped`` and is not charged
+    to a scope by the loop around it."""
+    rollout_batch = fedpg.rollout_batch
+
+    def planted(*args, **kwargs):
+        traj = rollout_batch(*args, **kwargs)
+        return traj._replace(losses=jnp.cos(traj.losses))
+
+    monkeypatch.setattr(fedpg, "rollout_batch", planted)
+    text = _round_program("streamed").as_text()
+    ops = scopes.hlo_scopes(text)
+    cos = [n for n, o in _named_ops(text) if o.endswith("/cos")]
+    assert cos and {ops[n] for n in cos} == {scopes.UNSCOPED}
+
+
+_SHARDED_SCRIPT = """
+import json, re, sys
+from repro.utils import platform as rplat
+rplat.apply_emulated_devices()
+import jax
+from repro.core import distribute, fedpg
+from repro.core.channel import RayleighChannel
+from repro.core.ota import OTAConfig
+from repro.rl.env import LandmarkNav
+from repro.rl.policy import MLPPolicy
+from repro.analyze.budget import CompileCounter
+from repro.telemetry import scopes
+
+assert jax.device_count() == 4, jax.devices()
+env, pol = LandmarkNav(), MLPPolicy()
+cfg = fedpg.FedPGConfig(n_agents=8, batch_m=2, horizon=4, n_rounds=1)
+ota = OTAConfig(channel=RayleighChannel(), noise_sigma=0.1, debias=True)
+mesh = distribute.agent_mesh_for(cfg.n_agents)
+out = {}
+for blocks in (None, 1):
+    fedpg.run_jit(env, pol, cfg, jax.random.key(0), ota=ota,
+                  agent_mesh=mesh, agent_blocks=blocks)
+    with CompileCounter() as c:
+        ops = scopes.op_scopes()
+    fn, args = scopes._LAST
+    text = fn.lower(*args).compile().as_text()
+    names = re.findall(r'%?([^\\s=]+) = [^\\n]*op_name="([^"]*/shard_map'
+                       r'[^"]*)"', text)
+    psums = re.findall(r'%?([^\\s=]+) = [^\\n]* all-reduce\\(', text)
+    out[str(blocks)] = {
+        "compiles": c.count,
+        "body": sorted({ops[n] for n, _ in names}),
+        "stray": sorted({o for n, o in names if ops[n] == scopes.UNSCOPED}),
+        "psum": sorted({ops[n] for n in psums}),
+        "scopes": sorted(set(ops.values()))}
+print(json.dumps(out))
+"""
+
+
+def test_sharded_round_scopes_on_four_devices():
+    """The agent_mesh round on 4 virtual CPU devices: every op of the
+    sharded body falls under one of the four scopes or is the shard_map's
+    or the block loop's own, the psums belong to the uplink or the
+    epilogue, and the map costs no compile."""
+    import os
+    import pathlib
+    import subprocess
+    import sys
+
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env.update(JAX_PLATFORMS="cpu", REPRO_EMULATED_DEVICES="4",
+               PYTHONPATH=str(repo / "src"))
+    out = subprocess.run([sys.executable, "-c", _SHARDED_SCRIPT], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-3000:]
+    res = json.loads(out.stdout.strip().splitlines()[-1])
+    for blocks, r in res.items():
+        assert r["compiles"] == 0, blocks
+        assert r["body"] and set(r["body"]) <= set(scopes.SCOPES) | {
+            scopes.CONTROL, scopes.UNSCOPED}, (blocks, r)
+        assert all(_LOOP_OWN.match(o) for o in r["stray"]), (blocks, r)
+        assert r["psum"] and set(r["psum"]) <= {scopes.UPLINK,
+                                                scopes.EPILOGUE}, (blocks, r)
+        assert set(scopes.SCOPES) <= set(r["scopes"]), (blocks, r)
+
+
+# ---------------------------------------------------------------------------
+# program spans on the profiler's clock; the service commit's spans
+# ---------------------------------------------------------------------------
+
+def test_span_lands_in_the_profile_host_plane(tmp_path):
+    """A span run under the profiler appears by name in the xplane's host
+    plane, inside the profiled window, at the time the tracer gave it."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    tr = rtrace.Tracer()
+    with jax.profiler.trace(str(tmp_path)):
+        with tr.span("service.fetch", round=3) as sp:
+            jnp.arange(4.0).block_until_ready()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    pd = ProfileData.from_file(path)
+    env = {k: v for p in pd.planes if p.name == "Task Environment"
+           for k, v in p.stats}
+    t0, t1 = env["profile_start_time"], env["profile_stop_time"]
+    events = [ev for p in pd.planes if p.name.startswith("/host:")
+              for line in p.lines for ev in line.events
+              if ev.name == "service.fetch"]
+    assert len(events) == 1
+    (ev,) = events
+    assert dict(ev.stats)["round"] == 3
+    assert 0 <= ev.start_ns and ev.start_ns + ev.duration_ns <= t1 - t0
+    # the tracer's wall clock is the profiler's: the Chrome export lines
+    # up with the xplane by its own profile_start_time
+    assert abs(sp.start_us * 1e3 - (t0 + ev.start_ns)) < 5e6
+    assert abs(sp.duration_us * 1e3 - ev.duration_ns) < 5e6
+
+
+def test_service_commit_spans_and_counters():
+    """A commit span holds dispatch, fetch and record children tagged with
+    the round, and counts the agents rolled out (phantom block padding
+    included), the realised participants and the backend compiles."""
+    svc = _service(agent_blocks=3)
+    recs = []
+    for _ in range(2):
+        recs.append(svc.commit())
+    commits = [s for s in rtrace.spans() if s.name == "service_commit"][-2:]
+    for sp, rec in zip(commits, recs):
+        assert [c.name for c in sp.children] == [
+            "service.dispatch", "service.fetch", "service.record"]
+        assert {c.attrs["round"] for c in [sp] + sp.children} == {
+            rec["round_start"]}
+        # 7 agents in blocks of 3: 3 blocks of 3 rolled out, 2 rounds
+        assert sp.attrs["agents_rolled_out"] == 2 * 9
+        assert sp.attrs["agents_participating"] == round(
+            rec["participation_rate"] * 7 * 2)
+        assert rec["wall_us"] == pytest.approx(
+            sp.children[0].duration_us + sp.children[1].duration_us)
+    assert commits[0].attrs["compiles"] >= 1
+    assert commits[1].attrs["compiles"] == 0
+
+
+def test_agents_rolled_out_follows_the_layout():
+    assert fedpg.agents_rolled_out(10) == 10
+    assert fedpg.agents_rolled_out(10 ** 4, 256) == 40 * 256
+    assert fedpg.agents_rolled_out(7, 100) == 8  # the block caps at ceil(N/2)
+
+
+def test_wait_keeps_a_span_visible_to_a_profiler_started_mid_wait(
+        tmp_path, monkeypatch):
+    """A span entered before the profiler started is missing from the
+    profile, unless it waits through ``trace.wait``: the wait re-enters
+    it once the profiler runs, so the profile's tail still names it."""
+    import glob
+    import threading
+
+    from jax.profiler import ProfileData
+
+    class Pending:  # a device result, ready once the gate opens
+        def __init__(self):
+            self.gate = threading.Event()
+
+        def block_until_ready(self):
+            assert self.gate.wait(timeout=60)
+            return self
+
+    reentered = threading.Event()
+    annotation = rtrace._annotation
+
+    def spy(name, attrs):
+        if rtrace._profiling():
+            reentered.set()
+        return annotation(name, attrs)
+
+    monkeypatch.setattr(rtrace, "_annotation", spy)
+    tr = rtrace.Tracer()
+    names = []
+    for name, waits in (("fetch.plain", False), ("fetch.waited", True)):
+        pending, entered = Pending(), threading.Event()
+        reentered.clear()
+
+        def commit():
+            with tr.span(name, round=7):
+                entered.set()
+                if waits:
+                    tr.wait({"a": pending, "b": jnp.ones(2)})
+                pending.block_until_ready()
+
+        th = threading.Thread(target=commit)
+        th.start()
+        assert entered.wait(timeout=60)
+        logdir = tmp_path / name
+        jax.profiler.start_trace(str(logdir))
+        if waits:
+            assert reentered.wait(timeout=60)
+        pending.gate.set()
+        th.join(timeout=60)
+        assert not th.is_alive()
+        jax.profiler.stop_trace()
+        (path,) = glob.glob(str(logdir / "plugins/profile/*/*.xplane.pb"))
+        names += [(ev.name, dict(ev.stats)) for p in
+                  ProfileData.from_file(path).planes
+                  if p.name.startswith("/host:") for line in p.lines
+                  for ev in line.events if ev.name.startswith("fetch.")]
+    assert names == [("fetch.waited", {"round": 7})]
+    assert [s.name for s in tr.spans()] == ["fetch.plain", "fetch.waited"]
+
+
+def test_wait_raises_what_the_device_wait_raised():
+    class Broken:
+        def block_until_ready(self):
+            raise RuntimeError("device lost")
+
+    tr = rtrace.Tracer()
+    with pytest.raises(RuntimeError, match="device lost"):
+        with tr.span("service.fetch"):
+            tr.wait([Broken()])
